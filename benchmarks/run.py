@@ -16,6 +16,8 @@ from __future__ import annotations
 import argparse
 import time
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def main() -> None:
     ap = argparse.ArgumentParser()
@@ -45,6 +47,7 @@ def main() -> None:
                          "engine-speedup drop, lost byte-identity, or recorded "
                          "serve sample errors)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.json_out is None:
         args.json_out = ("BENCH_build_ci.json" if args.ci
                          else "BENCH_build_quick.json" if args.quick

@@ -142,13 +142,12 @@ def _sampled_reach_density(g: CSRGraph, samples: int = 12, seed: int = 0) -> flo
 
 
 def _device_backend_available() -> bool:
-    """True when jax sees an accelerator (the device engine's auto gate)."""
-    try:
-        import jax
+    """True when jax sees an accelerator (the device engine's auto gate).
+    A jax that fails to start raises here: it must not quietly turn an
+    accelerator build into a host build."""
+    import jax
 
-        return jax.default_backend() != "cpu"
-    except Exception:  # jax missing/broken: host paths still work
-        return False
+    return jax.default_backend() != "cpu"
 
 
 def build_distribution_labels(

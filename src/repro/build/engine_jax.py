@@ -60,7 +60,7 @@ def _expand_fn(slabs, pos_of, n, wm, expand_impl, interpret, block_n, mesh):
         return jnp.bitwise_or.reduce(f_pad[idx], axis=1)
 
     if mesh is not None:
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         axes = tuple(ax for ax in mesh.axis_names if ax != "model")

@@ -23,7 +23,7 @@ from repro.core.distribution import distribution_labeling
 from repro.core.hierarchy import hierarchical_labeling
 from repro.core.oracle import ReachabilityOracle
 from repro.graph.csr import CSRGraph
-from repro.graph.scc import condense_to_dag
+from repro.graph.scc import COMP_ORDER, condense_to_dag
 from repro.serve.engine import QueryEngine
 from repro.serve.prefilter import topo_levels
 
@@ -115,6 +115,8 @@ def oracle_from_snapshot(
     condensation (``save_oracle(path, co.oracle)``); a snapshot of a
     different graph fails the cheap shape check here and answers garbage
     past it — persist snapshots are content-checksummed, not graph-keyed.
+    A snapshot whose rows are indexed in another SCC id order (any saved
+    before the order was recorded) is refused with ``CorruptSnapshotError``.
     """
     from repro.persist import load_oracle
 
@@ -123,9 +125,9 @@ def oracle_from_snapshot(
     dag, comp = condense_to_dag(g)
     report = None
     if mode == "strict":
-        oracle = load_oracle(path, strict=True)
+        oracle = load_oracle(path, strict=True, comp_order=COMP_ORDER)
     else:
-        oracle, report = load_oracle(path, strict=False)
+        oracle, report = load_oracle(path, strict=False, comp_order=COMP_ORDER)
     if oracle.n != dag.n:
         raise ValueError(
             f"snapshot at {path} indexes {oracle.n} vertices but the "

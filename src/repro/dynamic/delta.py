@@ -28,7 +28,7 @@ from typing import Dict, Iterable, List, Set, Tuple
 import numpy as np
 
 from repro.graph.csr import CSRGraph, from_edges
-from repro.graph.scc import tarjan_scc
+from repro.graph.scc import scc_ids, tarjan_scc
 
 # event kinds
 NOOP = "noop"
@@ -96,7 +96,7 @@ class CondensationState:
         for u in range(g.n):
             for w in self.out_adj[u]:
                 self.in_adj[w].add(u)
-        comp, k = tarjan_scc(g)
+        comp, k = scc_ids(g)
         self.comp = comp.astype(np.int32).copy()
         self.n_comp = int(k)
         self.members: List[List[int]] = [[] for _ in range(k)]

@@ -69,6 +69,40 @@ def bfs_levels(g: CSRGraph, u: int, max_steps: int | None = None) -> np.ndarray:
     return level
 
 
+def sample_reachability_batch(
+    g: CSRGraph,
+    n_queries: int,
+    rng: np.random.Generator,
+    per_source: int = 8,
+) -> tuple[np.ndarray, np.ndarray]:
+    """About half reachable pairs, with BFS truth, at any graph size.
+
+    Each sampled source (one with an out-edge) contributes up to
+    ``per_source`` vertices it reaches and as many random vertices it does
+    not, all decided by one ``reachable_set`` — memory is O(n) per source,
+    never the O(n^2 / 8) closure ``sample_query_workload`` builds.
+    Returns (queries int32[n_queries, 2], truth bool[n_queries])."""
+    sources = np.nonzero(np.diff(g.indptr) > 0)[0]
+    half = n_queries // 2
+    pos: list[tuple[int, int]] = []
+    neg: list[tuple[int, int]] = []
+    for _ in range(20 * n_queries):
+        if len(pos) >= half and len(neg) >= n_queries - half:
+            break
+        u = int(sources[rng.integers(0, sources.size)])
+        reach = reachable_set(g, u)
+        hits = np.nonzero(reach)[0]
+        k = min(per_source, hits.size, max(half - len(pos), 0))
+        pos += [(u, int(v)) for v in rng.choice(hits, size=k, replace=False)]
+        cand = rng.integers(0, g.n, size=2 * per_source)
+        cand = cand[~reach[cand] & (cand != u)]
+        neg += [(u, int(v)) for v in cand[: max(n_queries - half - len(neg), 0)]]
+    q = np.array(pos + neg, dtype=np.int32).reshape(-1, 2)
+    truth = np.array([True] * len(pos) + [False] * len(neg))
+    perm = rng.permutation(q.shape[0])
+    return q[perm], truth[perm]
+
+
 def sample_query_workload(
     g: CSRGraph,
     n_queries: int,

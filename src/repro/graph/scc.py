@@ -72,12 +72,31 @@ def tarjan_scc(g: CSRGraph) -> Tuple[np.ndarray, int]:
     return comp, n_comps
 
 
+# The order in which ``scc_ids`` (and so ``condense_to_dag``) numbers SCCs.
+# Label snapshots over a condensation record it; loaders refuse another.
+COMP_ORDER = "min-vertex"
+
+
+def scc_ids(g: CSRGraph) -> Tuple[np.ndarray, int]:
+    """SCC ids numbered in the order of each component's smallest vertex:
+    an acyclic graph keeps its vertex ids.  (Tarjan's reverse-topological
+    numbering would scramble the vertex order, and with it the rank
+    order's tie-breaks and the wave schedule built on them.)"""
+    comp, k = tarjan_scc(g)
+    first = np.full(k, g.n, dtype=np.int64)
+    np.minimum.at(first, comp, np.arange(g.n, dtype=np.int64))
+    relabel = np.empty(k, dtype=np.int32)
+    relabel[np.argsort(first, kind="stable")] = np.arange(k, dtype=np.int32)
+    return relabel[comp], k
+
+
 def condense_to_dag(g: CSRGraph) -> Tuple[CSRGraph, np.ndarray]:
     """Coalesce SCCs. Returns (dag, comp_id) with comp_id int32[n_original].
 
-    The resulting DAG vertex ids are the component ids.
+    The resulting DAG vertex ids are the component ids of ``scc_ids``, so
+    an acyclic graph condenses to itself, ids and all.
     """
-    comp, k = tarjan_scc(g)
+    comp, k = scc_ids(g)
     src, dst = g.edges()
     csrc, cdst = comp[src], comp[dst]
     keep = csrc != cdst

@@ -8,7 +8,7 @@ stationary tiles. Each grid step owns a (TN)-node tile; its padded neighbor
 ids are small int32 VMEM blocks, and source rows are pulled from the
 feature matrix (kept whole in ANY/HBM space) with dynamic row slices, one
 neighbor slot at a time, accumulating in a VMEM f32 tile. The dynamic row
-gather is the honest hot spot — on hardware each pl.load is a strided HBM
+gather is the honest hot spot — on hardware each dynamic row read is a strided HBM
 read issued by the scalar core (Mosaic supports dynamic sublane slices);
 interpret mode validates the semantics.
 
@@ -35,7 +35,7 @@ def _ell_spmm_kernel(nbr_ref, wgt_ref, x_ref, o_ref, *, block_n, max_deg):
         def row_body(i, acc):
             idx = nbr[i, s]
             safe = jnp.where(idx == INVALID, 0, idx)
-            row = pl.load(x_ref, (pl.dslice(safe, 1), slice(None)))  # [1, F]
+            row = x_ref[pl.ds(safe, 1), :]  # [1, F]
             w = jnp.where(idx == INVALID, 0.0, wgt[i, s])
             return acc.at[i].add(w * row[0])
 

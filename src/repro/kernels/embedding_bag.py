@@ -27,7 +27,7 @@ def _embag_kernel(idx_ref, table_ref, o_ref, *, block_b, bag):
         def row_body(b, acc):
             i = idx[b, s]
             safe = jnp.where(i < 0, 0, i)
-            row = pl.load(table_ref, (pl.dslice(safe, 1), slice(None)))
+            row = table_ref[pl.ds(safe, 1), :]
             valid = (i >= 0).astype(row.dtype)
             return acc.at[b].add(valid * row[0])
 

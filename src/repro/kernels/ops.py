@@ -7,6 +7,7 @@ container is CPU-only; TPU is the compile target).
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -18,7 +19,7 @@ from repro.kernels.ell_spmm import ell_spmm_pallas
 from repro.kernels.embedding_bag import embedding_bag_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.frontier_ell import frontier_or_pallas
-from repro.kernels.label_intersect import label_intersect_pallas
+from repro.kernels.label_intersect import label_intersect_pallas, slot_block
 
 INVALID = -1
 
@@ -39,14 +40,18 @@ def _pad_axis(x: jnp.ndarray, axis: int, multiple: int, fill) -> jnp.ndarray:
 
 @functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
 def label_intersect(a, b, block_b: int = 256, interpret: bool | None = None):
-    """int32[B, La] x int32[B, Lb] -> bool[B]."""
+    """int32[B, La] x int32[B, Lb] -> bool[B]. ``block_b`` is a multiple of
+    128 (queries run along the lane axis)."""
     if interpret is None:
         interpret = not _on_tpu()
     B = a.shape[0]
-    ap = _pad_axis(a, 0, block_b, INVALID)
-    bp = _pad_axis(b, 0, block_b, INVALID)
-    out = label_intersect_pallas(ap, bp, block_b=block_b, interpret=interpret)
-    return out[:B]
+    # the kernel wants slots on sublanes, queries on lanes
+    at = _pad_axis(_pad_axis(a, 0, block_b, INVALID).T, 0,
+                   slot_block(a.shape[1]), INVALID)
+    bt = _pad_axis(_pad_axis(b, 0, block_b, INVALID).T, 0,
+                   slot_block(b.shape[1]), INVALID)
+    out = label_intersect_pallas(at, bt, block_b=block_b, interpret=interpret)
+    return out[0, :B] != 0
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "block_k", "block_w", "interpret"))
@@ -106,13 +111,19 @@ def flash_attention(
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
 def frontier_or(nbr, f, block_n: int = 128, interpret: bool | None = None):
     """Packed-frontier ELL OR-gather: int32[r, d], uint32[n_src, WM] ->
-    uint32[r, WM] (one BFS level of the sparse device wave engine)."""
+    uint32[r, WM] (one BFS level of the sparse device wave engine).  Each
+    row's valid slots come first, INVALID padding after them — the layout
+    ``bitset.ell_slabs`` builds."""
     if interpret is None:
         interpret = not _on_tpu()
     r = nbr.shape[0]
     if r == 0:
         return jnp.zeros((0, f.shape[1]), dtype=jnp.uint32)
-    bn = min(block_n, r) if r % min(block_n, r) == 0 else r
+    # row tiles stay a multiple of 8 sublanes, and the tile's flattened ids
+    # (an SMEM block) a multiple of the 1024-word tile XLA lays 1-D int32
+    # arrays out in — unless one tile holds the whole slab
+    step = 1024 // math.gcd(nbr.shape[1], 1024)
+    bn = min(-(-max(block_n, step) // step) * step, -(-r // 8) * 8)
     nbrp = _pad_axis(nbr, 0, bn, INVALID)
     out = frontier_or_pallas(nbrp, f, block_n=bn, interpret=interpret)
     return out[:r]

@@ -35,6 +35,7 @@ import numpy as np
 from repro import obs
 from repro.core.api import build_oracle, oracle_from_snapshot
 from repro.ft import inject
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import metrics, trace
 from repro.serve.daemon import DaemonConfig, ServeDaemon
 from repro.serve.engine import select_backend
@@ -43,6 +44,17 @@ from repro.graph.generators import paper_dataset_analogue, random_dag
 from repro.graph.reach import reachable_set
 
 HOST_BACKENDS = ("host", "dense", "kernel")
+
+
+def device_info() -> dict:
+    """The device this run measured on, as JAX reports it."""
+    import jax
+
+    dev = jax.devices()[0]
+    return {"platform": dev.platform, "kind": dev.device_kind,
+            "count": len(jax.devices()),
+            # the Pallas kernels run interpreted everywhere but on a TPU
+            "pallas_interpret": dev.platform != "tpu"}
 
 
 def make_graph(args):
@@ -200,8 +212,7 @@ def run_sweep(args) -> None:
             "batch": args.batch,
             "label_ints": oracle.total_label_size,
             "tier_widths": oracle.engine.widths,
-            "jax_platform": __import__("jax").default_backend(),
-            "note": "kernel backend runs the Pallas kernel in interpret mode off-TPU",
+            "device": device_info(),
             "backends": records,
         }
         # preserve sections other writers own (the open_loop rows)
@@ -478,6 +489,7 @@ def main() -> None:
                          "(obs.disable(); the overhead-guard baseline)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     if args.no_obs:
         obs.disable()
     if args.mode == "daemon":

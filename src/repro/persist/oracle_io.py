@@ -24,6 +24,13 @@ Per-row-block corruption semantics by block kind:
   * a budgeted store's ``trunc_mask_out`` / ``trunc_mask_in`` -> that whole
     side is treated as truncated (all-True mask): truncation marks only
     route misses to the exact-search rung, so over-marking is always safe.
+
+Oracle and budgeted snapshots record the SCC id order their rows are
+indexed in (``graph.scc.COMP_ORDER``).  ``load_budgeted``, and
+``load_oracle`` given ``comp_order``, refuse even non-strict a snapshot
+indexed in another order: rows of one condensation read under another's
+ids answer wrong without any error.  Snapshots that predate the record
+were indexed in Tarjan order.
 """
 from __future__ import annotations
 
@@ -33,6 +40,7 @@ from typing import List, Optional, Tuple
 
 import numpy as np
 
+from repro.graph.scc import COMP_ORDER
 from repro.persist.blocks import CorruptSnapshotError, load_blocks, save_blocks
 
 ROW_BLOCK = 4096
@@ -79,9 +87,19 @@ def _oracle_arrays(oracle, row_block: int) -> Tuple[dict, dict]:
 def save_oracle(path: str, oracle, row_block: int = ROW_BLOCK, extra_meta: Optional[dict] = None) -> str:
     """Atomic, checksummed snapshot of a finalized oracle."""
     arrays, meta = _oracle_arrays(oracle, row_block)
+    meta["comp_order"] = COMP_ORDER
     if extra_meta:
         meta.update(extra_meta)
     return save_blocks(path, arrays, meta)
+
+
+def _check_comp_order(path: str, meta: dict, comp_order: Optional[str]) -> None:
+    found = meta.get("comp_order", "tarjan")
+    if comp_order is not None and found != comp_order:
+        raise CorruptSnapshotError(
+            f"{path}: label rows are indexed by SCC ids in {found!r} order, "
+            f"but this condensation numbers them in {comp_order!r} order — "
+            "rebuild the snapshot")
 
 
 def _assemble_side(name, arrays, meta, n, width, bad_rows):
@@ -127,16 +145,18 @@ def _load_oracle_parts(arrays, meta, bad):
     return oracle, LoadReport(bad_blocks=list(bad), quarantine_out=q_out, quarantine_in=q_in)
 
 
-def load_oracle(path: str, strict: bool = True):
+def load_oracle(path: str, strict: bool = True, comp_order: Optional[str] = None):
     """Load + verify an oracle snapshot.
 
     ``strict=True``: returns the oracle, raises ``CorruptSnapshotError`` on
     ANY checksum mismatch.  ``strict=False``: returns ``(oracle, report)``
-    with corrupt row blocks zeroed and quarantined in the report."""
+    with corrupt row blocks zeroed and quarantined in the report.
+    ``comp_order``: refuse rows indexed in another SCC id order."""
     arrays, meta, bad = load_blocks(path, strict=strict)
     if meta.get("kind") != "ReachabilityOracle":
         raise CorruptSnapshotError(
             f"{path}: expected a ReachabilityOracle snapshot, found {meta.get('kind')!r}")
+    _check_comp_order(path, meta, comp_order)
     oracle, report = _load_oracle_parts(arrays, meta, bad)
     return oracle if strict else (oracle, report)
 
@@ -155,6 +175,7 @@ def save_budgeted(path: str, store, row_block: int = ROW_BLOCK) -> str:
     arrays["trunc_mask_in"] = packed_in
     meta.update(
         kind="BudgetedOracle",
+        comp_order=COMP_ORDER,
         rank_cut=int(store.rank_cut),
         budget_bytes=int(store.budget_bytes),
         resident_bytes=int(store.resident_bytes),
@@ -165,7 +186,7 @@ def save_budgeted(path: str, store, row_block: int = ROW_BLOCK) -> str:
 
 def load_budgeted(path: str, strict: bool = True):
     """Load + verify a budget-truncated store (see ``load_oracle`` for the
-    strictness contract).
+    strictness contract; rows in another SCC id order are refused).
 
     Corruption semantics COMPOSE with the row-block semantics above: label
     row blocks quarantine exactly as in ``load_oracle`` (the report's masks
@@ -180,6 +201,7 @@ def load_budgeted(path: str, strict: bool = True):
     if meta.get("kind") != "BudgetedOracle":
         raise CorruptSnapshotError(
             f"{path}: expected a BudgetedOracle snapshot, found {meta.get('kind')!r}")
+    _check_comp_order(path, meta, COMP_ORDER)
     oracle, report = _load_oracle_parts(arrays, meta, bad)
     n = int(meta["n"])
 

@@ -277,9 +277,11 @@ class BudgetController:
     def full_oracle(self):
         """The full store: retained, or reloaded from the snapshot."""
         if self._full is None:
+            from repro.graph.scc import COMP_ORDER
             from repro.persist import load_oracle
 
-            self._full = load_oracle(self.snapshot_path, strict=True)
+            self._full = load_oracle(self.snapshot_path, strict=True,
+                                     comp_order=COMP_ORDER)
             self.full_bytes = label_bytes(self._full)
         return self._full
 

@@ -137,18 +137,21 @@ def make_hop_sharded_serve_step(mesh, model_axis="model", data_axes=("pod", "dat
     query_sharding = NamedSharding(mesh, P(data_axes, None))
     out_sharding = NamedSharding(mesh, P(data_axes))
 
-    def step(L_out, L_in, queries):
-        a = jnp.take(L_out, queries[:, 0], axis=0)
-        b_full = jnp.take(L_in, queries[:, 1], axis=0)
-        # each hop-shard of `a` must compare against ALL hops of b:
-        # jnp ops under jit+sharding constraints let XLA insert the all-gather
-        # of the (small) b rows; the big L_out stays sharded.
-        eq = a[:, :, None] == b_full[:, None, :]
-        valid = (a[:, :, None] != INVALID) & (b_full[:, None, :] != INVALID)
-        return (eq & valid).any(axis=(1, 2))
+    def local(lo, li, q):
+        # each hop-shard of `a` must compare against ALL hops of b: gather
+        # the (small) b rows over the model axis; the big label matrices
+        # stay sharded, and the partial hits OR-reduce over the model axis
+        a = jnp.take(lo, q[:, 0], axis=0)
+        b = jax.lax.all_gather(jnp.take(li, q[:, 1], axis=0), model_axis,
+                               axis=1, tiled=True)
+        hit = intersect_rows(a, b).astype(jnp.int32)
+        return jax.lax.pmax(hit, model_axis) > 0
 
     fn = jax.jit(
-        step,
+        jax.shard_map(local, mesh=mesh,
+                      in_specs=(P(None, model_axis), P(None, model_axis),
+                                P(data_axes, None)),
+                      out_specs=P(data_axes)),
         in_shardings=(label_sharding, label_sharding, query_sharding),
         out_shardings=out_sharding,
     )
@@ -275,11 +278,14 @@ class QueryEngine:
         self.model_axis = model_axis
         self.comp_source = comp_source
         self.epoch = int(epoch)
-        self._lo, self._li = oracle.device_labels()
+        self._lo, self._li = self._place_labels(oracle)
         self.widths = tier_widths(
             oracle.out_len, oracle.in_len, oracle.max_label_len, n_tiers=n_tiers
         )
         self._sharded_fns: dict = {}
+        # (backend, source L_out, source L_in, resharded pair): labels in a
+        # per-call override's mesh layout, kept until the source changes
+        self._resharded: Optional[tuple] = None
         self.last_stats: dict = {}
         self._fallback_graph = fallback_graph
         self._fallback_csr = None   # resolved (graph, reverse) pair, lazy
@@ -298,6 +304,21 @@ class QueryEngine:
         # set_budget so a batch's entry-time capture is internally consistent
         self._budget_view: Optional[tuple] = None
 
+    def _place_labels(self, oracle):
+        """Device label matrices laid out for the default backend; every
+        upload (full, refreshed or budget-truncated labels) goes through
+        here.  The mesh backends upload straight from the host arrays into
+        their sharding (replicated, or split along the hop dim over the
+        model axis), so no full copy ever lands on one device."""
+        if self.backend not in ("sharded", "sharded_hop"):
+            return oracle.device_labels()
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        spec = P(None, self.model_axis) if self.backend == "sharded_hop" else P()
+        sharding = NamedSharding(self.mesh, spec)
+        return (jax.device_put(oracle.L_out, sharding),
+                jax.device_put(oracle.L_in, sharding))
+
     # ---------------------------------------------------------- publishing
 
     def refresh(self, oracle, level: Optional[np.ndarray] = None,
@@ -314,7 +335,7 @@ class QueryEngine:
         self.oracle = oracle
         if level is not None:
             self.level = np.array(level, dtype=np.int32)  # copy: see __init__
-        self._lo, self._li = oracle.device_labels()
+        self._lo, self._li = self._place_labels(oracle)
         self.widths = tier_widths(
             oracle.out_len, oracle.in_len, oracle.max_label_len, n_tiers=self.n_tiers
         )
@@ -405,7 +426,7 @@ class QueryEngine:
             self._budget_view = None
             return
         t = store.oracle
-        lo, li = t.device_labels()
+        lo, li = self._place_labels(t)
         widths = tier_widths(t.out_len, t.in_len, t.max_label_len,
                              n_tiers=self.n_tiers)
         self._budget_view = (store, lo, li, widths)
@@ -600,6 +621,45 @@ class QueryEngine:
             self._tally(stats, degraded)
             return out
 
+    def warmup(self, max_batch: int, backend: Optional[str] = None) -> int:
+        """Compile every device program a batch of up to ``max_batch``
+        queries can dispatch: each (tier width, power-of-two tile) pair of
+        the planner, or each daemon pad size without bucketing.
+
+        Runs OUTSIDE the degradation ladder: a device program that fails
+        here is a fault in the program, not a runtime device fault, so it
+        raises instead of being served on the host.  Returns the number of
+        programs run."""
+        backend = self.backend if backend is None else select_backend(backend, self.mesh)
+        if backend == "host":
+            return 0
+        bv = self._budget_view
+        lo, li, widths = ((self._lo, self._li, self.widths) if bv is None
+                          else (bv[1], bv[2], bv[3]))
+
+        def ladder(lo_rows: int) -> list:  # powers of two up to max_batch
+            sizes = [lo_rows]
+            while sizes[-1] < max_batch:
+                sizes.append(sizes[-1] * 2)
+            return sizes
+
+        use_kernel = backend == "kernel"
+        if backend in ("dense", "kernel") and self.bucketing:
+            # the planner pads each tier to a power of two from min_tile
+            runs = [_tier_intersect(lo, li, jnp.zeros((r, 2), jnp.int32), w, use_kernel)
+                    for r in ladder(self.min_tile) for w in widths]
+        else:
+            # the daemon pads each batch to a power of two from 64, capped
+            sizes = sorted({min(s, max_batch) for s in ladder(64)})
+            if backend in ("dense", "kernel"):
+                runs = [serve_step(lo, li, jnp.zeros((s, 2), jnp.int32),
+                                   use_kernel=use_kernel) for s in sizes]
+            else:
+                runs = [self._sharded_batch(np.zeros((s, 2), np.int32), backend,
+                                            view=bv) for s in sizes]
+        jax.block_until_ready(runs)
+        return len(runs)
+
     def _host_batch(self, rest: np.ndarray, o=None) -> np.ndarray:
         o = self.oracle if o is None else o
         return np.fromiter((o.query(int(u), int(v)) for u, v in rest), dtype=bool,
@@ -656,15 +716,24 @@ class QueryEngine:
                        view: Optional[tuple] = None) -> np.ndarray:
         inject.fire("serve.device_dispatch", backend=backend)
         lo, li = (self._lo, self._li) if view is None else (view[1], view[2])
-        fn = self._sharded_fns.get(backend)
-        if fn is None:
+        made = self._sharded_fns.get(backend)
+        if made is None:
             if backend == "sharded":
-                fn, _, _ = make_sharded_serve_step(self.mesh, data_axes=self.data_axes)
+                made = make_sharded_serve_step(self.mesh, data_axes=self.data_axes)
             else:
-                fn, _, _ = make_hop_sharded_serve_step(
+                made = make_hop_sharded_serve_step(
                     self.mesh, model_axis=self.model_axis, data_axes=self.data_axes
                 )
-            self._sharded_fns[backend] = fn
+            self._sharded_fns[backend] = made
+        fn, (label_sharding, _, _), _ = made
+        if backend != self.backend:
+            # labels are laid out for the default backend: a per-call
+            # override reshards them once per label store, not per batch
+            r = self._resharded
+            if r is None or r[0] != backend or r[1] is not lo or r[2] is not li:
+                r = (backend, lo, li, jax.device_put((lo, li), label_sharding))
+                self._resharded = r
+            lo, li = r[3]
         # fixed shapes across devices: pad the batch to a data-shard multiple
         shards = 1
         for ax in self.data_axes or ():

@@ -106,25 +106,19 @@ def run_open_loop(
                for _ in range(arrivals.shape[0])]
 
     daemon = ServeDaemon(target, cfg, budget_ctl=budget_ctl)
-    # warm every rung of the daemon's padded-dispatch ladder before the
-    # clock starts (outside any fault plan, so injected occurrences hit the
-    # measured run): each distinct batch shape pays device compile —
-    # hundreds of ms — which would otherwise stall the queue mid-run and
-    # expire a wave of arrivals that says nothing about steady-state
-    # overload behavior
+    # compile every device program the run can dispatch before the clock
+    # starts (outside any fault plan, so injected occurrences hit the
+    # measured run): each distinct shape pays device compile — hundreds of
+    # ms — which would otherwise stall the queue mid-run and expire a wave
+    # of arrivals that says nothing about steady-state overload behavior.
+    # The warm-up bypasses the degradation ladder: a program that fails to
+    # compile or run raises here instead of being served on the host.
     warm_sp = (trace.span("openloop.warmup", cat="openloop",
                           args={"max_batch": cfg.max_batch})
                if ON.enabled else trace.NOOP_SPAN)
     with warm_sp:
-        size = 64
-        while True:
-            wq = rng.integers(0, g.n, size=(min(size, cfg.max_batch), 2)).astype(
-                np.int32)
-            daemon.engine.query_batch(wq, backend=cfg.backend)
-            if size >= cfg.max_batch:
-                break
-            size *= 2
-    daemon.engine.reset_stats()
+        daemon.engine.warmup(cfg.max_batch, backend=cfg.backend)
+    daemon.engine.reset_stats()   # the report counts this run's batches only
     answered: list = []
     shed: Dict[str, int] = {}
     drive_sp = (trace.span("openloop.drive", cat="openloop",

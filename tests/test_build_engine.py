@@ -145,6 +145,21 @@ def test_dfs_intervals_sound(rng):
                 assert L[t, u] <= P[t, v] <= P[t, u], (u, int(v), t)
 
 
+def test_device_gate_does_not_swallow_a_broken_jax(monkeypatch):
+    """A jax that fails to start must surface, not quietly route an
+    accelerator build to a host engine."""
+    import jax
+
+    from repro.build.engine import _device_backend_available
+
+    def broken():
+        raise RuntimeError("backend failed to initialize")
+
+    monkeypatch.setattr(jax, "default_backend", broken)
+    with pytest.raises(RuntimeError, match="failed to initialize"):
+        _device_backend_available()
+
+
 def test_auto_impl_routes_and_matches(rng):
     g = random_dag(300, 900, seed=9)
     auto = distribution_labeling(g)  # n < 4096 -> reference path
@@ -268,6 +283,19 @@ def test_device_engine_label_matrix_growth(rng):
     dev2 = distribution_labeling_device(g2, max_wave=4, l_max=4, expand="xla")
     assert dev2.L_out.shape == ref2.L_out.shape == (3, 8)
     _assert_identical(ref2, dev2, "min width pad")
+
+
+@pytest.mark.parametrize("prune_cap", [2, 8, 64])
+def test_device_engine_prune_cap_fallback(prune_cap):
+    """Caps far below the cone sizes force the all-rows fallback of the
+    compacted prune gather mid-build; labels must stay byte-identical."""
+    from repro.build.engine_jax import distribution_labeling_device
+
+    for name, g in _dag_families(np.random.default_rng(5)):
+        ref = build_distribution_labels(g, impl="reference")
+        dev = distribution_labeling_device(g, max_wave=16, expand="xla",
+                                           prune_cap=prune_cap)
+        _assert_identical(ref, dev, f"{name} prune_cap {prune_cap}")
 
 
 def test_device_engine_pallas_interpret_row():

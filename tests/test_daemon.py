@@ -330,3 +330,39 @@ def test_health_surfaces_breaker_and_degradation(co, rng):
     assert h["counters"]["answered"] == 64
     assert "degradation" in h["engine"]
     assert h["shed_rate"] == 0.0
+
+
+# ------------------------------------------------------------- warm-up
+
+
+def test_engine_warmup_runs_every_tier_shape(co):
+    eng = co.engine
+    before = dict(eng.degradation)
+    rows = 0
+    size = eng.min_tile
+    while True:
+        rows += 1
+        if size >= 512:
+            break
+        size *= 2
+    assert eng.warmup(512, backend="dense") == rows * len(eng.widths)
+    assert eng.warmup(512, backend="host") == 0
+    assert eng.degradation == before
+
+
+def test_open_loop_warmup_raises_device_program_faults(co, monkeypatch):
+    """A device program that fails before the clock starts is a fault in
+    the program: it raises, instead of being served on the host ladder."""
+    from repro.serve import engine as engine_mod
+    from repro.serve.openloop import run_open_loop
+
+    def broken(*a, **k):
+        raise RuntimeError("tier program failed to lower")
+
+    before = dict(co.engine.degradation)
+    monkeypatch.setattr(engine_mod, "_tier_intersect", broken)
+    with pytest.raises(RuntimeError, match="failed to lower"):
+        run_open_loop(co, G, duration_s=0.2,
+                      config=DaemonConfig(backend="dense", max_batch=256))
+    assert co.engine.degradation == before
+

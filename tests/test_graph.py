@@ -22,6 +22,7 @@ from repro.graph.reach import (
     reachable_set,
     reaches_bit,
     sample_query_workload,
+    sample_reachability_batch,
     transitive_closure_bits,
 )
 from repro.graph.scc import condense_to_dag, tarjan_scc
@@ -93,6 +94,26 @@ def test_scc_condensation():
     assert comp[0] == comp[1] == comp[2]
     assert comp[3] == comp[4] == comp[5]
     assert comp[0] != comp[3] != comp[6]
+
+
+def test_scc_condensation_keeps_vertex_order():
+    """An acyclic graph condenses to itself; SCC ids follow each
+    component's smallest vertex."""
+    g = random_dag(200, 600, seed=3)
+    dag, comp = condense_to_dag(g)
+    assert np.array_equal(comp, np.arange(g.n))
+    assert np.array_equal(dag.indptr, g.indptr) and np.array_equal(dag.indices, g.indices)
+    cyc = from_edges(5, [4, 3, 1, 2], [3, 4, 2, 1])  # {1,2} and {3,4}
+    _, comp = condense_to_dag(cyc)
+    assert comp.tolist() == [0, 1, 1, 2, 2]
+
+
+def test_reachability_batch_balance_and_truth():
+    g = layered_dag(400, 1.5, seed=2)
+    q, truth = sample_reachability_batch(g, 300, np.random.default_rng(0))
+    assert q.shape == (300, 2) and 0.4 <= truth.mean() <= 0.6
+    for (u, v), t in zip(q, truth):
+        assert bool(reachable_set(g, int(u))[v]) == t
 
 
 def test_tc_bits_vs_dfs():

@@ -131,6 +131,57 @@ def test_epoch_save_load_round_trip(tmp_path, rng):
         load_epoch(p, strict=False)
 
 
+@pytest.mark.parametrize("mode", ["strict", "quarantine"])
+def test_snapshot_in_another_scc_order_refused(tmp_path, mode):
+    """Label rows saved under another SCC numbering (snapshots from before
+    the order was recorded used Tarjan's) must be refused, not served."""
+    from repro.core.api import build_oracle, oracle_from_snapshot
+    from repro.graph.csr import from_edges
+    from repro.graph.reach import reachable_set
+    from repro.persist.oracle_io import _oracle_arrays
+
+    rng = np.random.default_rng(8)
+    g = from_edges(80, rng.integers(0, 80, 200), rng.integers(0, 80, 200))
+    co = build_oracle(g, backend="host")
+    fresh = save_oracle(str(tmp_path / "fresh"), co.oracle)
+    back = oracle_from_snapshot(g, fresh, mode=mode, backend="host")
+    q = rng.integers(0, g.n, size=(300, 2))
+    truth = np.array([u == v or reachable_set(g, int(u))[v] for u, v in q])
+    assert np.array_equal(back.serve(q), truth)
+
+    arrays, meta = _oracle_arrays(co.oracle, 4096)  # no recorded order
+    legacy = save_blocks(str(tmp_path / "legacy"), arrays, meta)
+    with pytest.raises(CorruptSnapshotError, match="'tarjan' order"):
+        oracle_from_snapshot(g, legacy, mode=mode, backend="host")
+    load_oracle(legacy)  # a plain load, bound to no condensation, still works
+
+
+def test_budget_snapshots_refuse_another_scc_order(tmp_path):
+    from repro.core.api import build_oracle
+    from repro.persist.oracle_io import _oracle_arrays
+    from repro.serve.budget import BudgetController, label_bytes
+
+    co = build_oracle(random_dag(100, 340, seed=16))
+    arrays, meta = _oracle_arrays(co.oracle, 4096)
+    meta["comp_order"] = "tarjan"
+    path = save_blocks(str(tmp_path / "full"), arrays, meta)
+    with pytest.raises(CorruptSnapshotError, match="'tarjan' order"):
+        BudgetController(co.engine, budget_bytes=label_bytes(co.oracle) // 2,
+                         snapshot_path=path, retain_full=False)
+
+    from repro.persist import load_budgeted, save_budgeted
+    from repro.serve.budget import truncate_store
+
+    st = truncate_store(co.oracle, budget_bytes=label_bytes(co.oracle) // 2)
+    budgeted = save_budgeted(str(tmp_path / "budgeted"), st)
+    assert load_budgeted(budgeted).rank_cut == st.rank_cut
+    arrays, meta, _ = load_blocks(budgeted)
+    del meta["comp_order"]
+    legacy = save_blocks(str(tmp_path / "legacy"), arrays, meta)
+    with pytest.raises(CorruptSnapshotError, match="'tarjan' order"):
+        load_budgeted(legacy, strict=False)
+
+
 def test_oracle_kind_mismatch_refused(tmp_path):
     p = save_blocks(str(tmp_path / "other"), {"x": np.arange(3)}, {"kind": "zzz"})
     with pytest.raises(CorruptSnapshotError, match="expected a ReachabilityOracle"):

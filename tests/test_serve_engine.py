@@ -190,6 +190,19 @@ eng = QueryEngine(o, mesh=mesh, data_axes=('data',))
 for be in ('sharded', 'sharded_hop'):
     pred = eng.query_batch(np.asarray(q), backend=be)
     assert (pred == truth).all(), be
+    assert not any(eng.degradation.values()), (be, eng.degradation)
+# the override's layout is resharded once per label store, not per batch
+lo_hop = eng._resharded[3][0]
+eng.query_batch(np.asarray(q), backend='sharded_hop')
+assert eng._resharded[3][0] is lo_hop
+# budget-truncated labels land in the mesh layout too, never on one device
+from repro.serve.budget import BudgetController, label_bytes
+hop = QueryEngine(o, backend='sharded_hop', mesh=mesh, data_axes=('data',),
+                  fallback_graph=g)
+BudgetController(hop, budget_bytes=label_bytes(o) // 2)
+lo = hop._budget_view[1]
+assert lo.sharding.spec == jax.sharding.PartitionSpec(None, 'model'), lo.sharding
+assert (hop.query_batch(np.asarray(q)) == truth).all()
 print('SHARDED_ENGINE_OK')
 """
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
