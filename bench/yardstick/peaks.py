@@ -1,0 +1,15 @@
+"""Published peaks of each accelerator, keyed by JAX's ``device_kind``."""
+from __future__ import annotations
+
+import json
+import pathlib
+
+_TABLE = pathlib.Path(__file__).with_name("peaks.json")
+
+
+def peaks(device_kind: str) -> dict:
+    """The peaks of ``device_kind``; a kind not in the table is an error."""
+    table = json.loads(_TABLE.read_text())["devices"]
+    if device_kind not in table:
+        raise KeyError(f"no peaks for device kind {device_kind!r} in {_TABLE.name}")
+    return table[device_kind]
