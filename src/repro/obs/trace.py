@@ -22,10 +22,13 @@ manager and ``event()`` returns immediately; hot call sites additionally
 guard on ``ON.enabled`` before building args dicts, making the disabled
 path allocation-free.
 
-``annotate=True`` spans also enter a ``jax.profiler.TraceAnnotation`` (when
-jax is importable and annotations are switched on via
-``TRACER.jax_annotations = True``), so device spans line up with XLA's own
-profiler timeline.
+``annotate=True`` spans — ``span()`` and ``begin()`` alike — also enter a
+``jax.profiler.TraceAnnotation`` (when jax is importable and annotations are
+switched on via ``TRACER.jax_annotations = True``), so host stages land in
+the profiler's host plane beside the device events.  The ring stamps ``ts``
+on the profiler's host clock (``CLOCK``: ``time.time_ns()``, in µs), so an
+exported Chrome trace lays over a device trace without a shift: a profiler
+host event sits at ``profile_start_time + start_ns`` on the same clock.
 
 .. _Trace Event Format:
    https://docs.google.com/document/d/1CvAClvFfyA5R-PhYUmn5OOQtYMH4h6I0nSsKchNAySU
@@ -41,11 +44,27 @@ from typing import Dict, Optional
 
 from repro.obs.state import ON
 
-_EPOCH_NS = time.perf_counter_ns()
+# the profiler stamps host events on CLOCK_REALTIME (its ``profile_start_time``
+# plus an offset), so the ring does too
+CLOCK = "CLOCK_REALTIME us (time.time_ns), the profiler's host clock"
 
 
 def _now_us() -> float:
-    return (time.perf_counter_ns() - _EPOCH_NS) / 1000.0
+    return time.time_ns() / 1000.0
+
+
+def _annotation(tracer: "Tracer", name: str, annotate: bool):
+    """An entered ``jax.profiler.TraceAnnotation`` when the span asks for one
+    and the tracer mirrors into the profiler; else None."""
+    if not (annotate and tracer.jax_annotations):
+        return None
+    try:
+        import jax
+        ann = jax.profiler.TraceAnnotation(name)
+        ann.__enter__()
+        return ann
+    except Exception:
+        return None
 
 
 class _NoopSpan:
@@ -70,7 +89,7 @@ NOOP_SPAN = _NoopSpan()
 
 
 class _Span:
-    __slots__ = ("tracer", "name", "cat", "args", "t0", "_ann")
+    __slots__ = ("tracer", "name", "cat", "args", "annotate", "t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  args: Optional[dict], annotate: bool):
@@ -78,18 +97,13 @@ class _Span:
         self.name = name
         self.cat = cat
         self.args = args
+        self.annotate = annotate
         self._ann = None
-        if annotate and tracer.jax_annotations:
-            try:
-                import jax
-                self._ann = jax.profiler.TraceAnnotation(name)
-            except Exception:
-                self._ann = None
-        self.t0 = _now_us()
+        self.t0 = 0.0
 
     def __enter__(self):
-        if self._ann is not None:
-            self._ann.__enter__()
+        self._ann = _annotation(self.tracer, self.name, self.annotate)
+        self.t0 = _now_us()
         return self
 
     def __exit__(self, *exc):
@@ -152,20 +166,30 @@ class Tracer:
             return NOOP_SPAN
         return _Span(self, name, cat, args, annotate)
 
-    def begin(self, name: str, cat: str = "", args: Optional[dict] = None):
-        """Explicit begin for spans that end in another thread/callback;
-        finish with ``end(token)``."""
+    def begin(self, name: str, cat: str = "", args: Optional[dict] = None,
+              annotate: bool = False):
+        """Explicit begin for spans that end in another thread/callback or
+        across an ``await``; finish with ``end(token)``.  With ``annotate``
+        the span's ``TraceAnnotation`` stays open until that ``end``."""
         if not ON.enabled:
             return None
-        return (name, cat, args, _now_us())
+        return (name, cat, args, _now_us(), _annotation(self, name, annotate))
 
-    def end(self, token, **extra) -> None:
-        if token is None or not ON.enabled:
-            return
-        name, cat, args, t0 = token
+    def end(self, token, **extra) -> Optional[float]:
+        """Finish a ``begin`` span; returns its duration in µs (None when
+        nothing was recorded)."""
+        if token is None:
+            return None
+        name, cat, args, t0, ann = token
+        dur = _now_us() - t0
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        if not ON.enabled:
+            return None
         if extra:
             args = dict(args or {}, **extra)
-        self._complete(name, cat, t0, _now_us() - t0, args)
+        self._complete(name, cat, t0, dur, args)
+        return dur
 
     def event(self, name: str, cat: str = "", **args) -> None:
         """Instant ("i") event — terminal sheds, breaker flips, faults."""
@@ -186,11 +210,9 @@ class Tracer:
             f.write("\n")
 
     def chrome_payload(self, meta: Optional[dict] = None) -> dict:
-        payload = {"traceEvents": sorted(self.events, key=lambda e: e["ts"]),
-                   "displayTimeUnit": "ms"}
-        if meta:
-            payload["metadata"] = meta
-        return payload
+        return {"traceEvents": sorted(self.events, key=lambda e: e["ts"]),
+                "displayTimeUnit": "ms",
+                "metadata": dict(meta or {}, clock=CLOCK)}
 
     def clear(self) -> None:
         self.events.clear()

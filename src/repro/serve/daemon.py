@@ -48,6 +48,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from repro.obs import metrics, trace
+from repro.obs.stages import GC_PAUSES, StageFamily
 from repro.obs.state import ON
 
 
@@ -190,11 +191,17 @@ _QUEUE_DEPTH = metrics.gauge(
 _REQ_LATENCY = metrics.histogram(
     "daemon_request_latency_ms", "answered requests, arrival -> future resolve")
 _DISPATCH_MS = metrics.histogram(
-    "daemon_dispatch_ms", "padded-batch dispatch wall time (worker thread)")
-_BUDGET_STEPS = metrics.counter(
-    "daemon_budget_steps_total",
-    "pressure-loop budget steps taken between dispatch ticks",
-    labelnames=("direction",))
+    "daemon_dispatch_ms", "padded-batch dispatch wall time, timed on the event "
+    "loop around the executor hand-off")
+# one observation per tick of each stage that ran:
+#   wait     the queue is empty until a tick's first request
+#   collect  first request -> hand-off (batching window, expiry, concat)
+#   handoff  the loop's dispatch time less the worker's own time
+#   pad      padding to the power-of-two rung (worker)
+#   resolve  setting the futures and the latency observations
+_STAGES = StageFamily(
+    "daemon_stage_ms", "daemon tick time by stage",
+    ("wait", "collect", "handoff", "pad", "resolve"), cat="daemon")
 
 _COUNTER_METRICS = {
     "submitted": _REQUESTS.labels(event="submitted"),
@@ -211,8 +218,6 @@ _COUNTER_METRICS = {
     "pinned_epoch_batches": _BATCHES.labels(rung="pinned_epoch"),
     "pinned_device_to_host": _BATCHES.labels(rung="pinned_host"),
     "publishes": _PUBLISHES.labels(),
-    "budget_steps_down": _BUDGET_STEPS.labels(direction="down"),
-    "budget_steps_up": _BUDGET_STEPS.labels(direction="up"),
 }
 
 
@@ -263,6 +268,7 @@ class ServeDaemon:
         self._engine_lock = threading.Lock()
         self._loop_task: Optional[asyncio.Task] = None
         self._pressure_task: Optional[asyncio.Task] = None
+        self._gc_hooked = False   # GC_PAUSES installed by this daemon
 
     def _count(self, key: str, n: int = 1) -> None:
         """Bump the per-instance counter AND its registry mirror, so the
@@ -278,7 +284,14 @@ class ServeDaemon:
         self._loop_task = asyncio.ensure_future(self._run())
         if self.budget_ctl is not None and self.budget_ctl.pressure is not None:
             self._pressure_task = asyncio.ensure_future(self._pressure_loop())
+        GC_PAUSES.install()
+        self._gc_hooked = True
         self.state = "ready"
+
+    def _unhook_gc(self) -> None:
+        if self._gc_hooked:
+            GC_PAUSES.remove()
+            self._gc_hooked = False
 
     async def drain(self) -> dict:
         """Graceful shutdown: stop admitting, serve the admitted backlog,
@@ -287,6 +300,7 @@ class ServeDaemon:
         while self._queued > 0 or self._inflight > 0:
             await asyncio.sleep(self.cfg.batch_window_ms / 1000.0)
         await self._stop_loop()
+        self._unhook_gc()
         self.state = "stopped"
         return dict(self.counters)
 
@@ -311,6 +325,7 @@ class ServeDaemon:
                     trace.event("shed", cat="request", reason="killed",
                                 trace_id=req.trace_id)
         self._queued = 0
+        self._unhook_gc()
         self.state = "killed"
 
     async def _stop_loop(self) -> None:
@@ -346,10 +361,11 @@ class ServeDaemon:
         while True:
             await asyncio.sleep(interval)
             step = await loop.run_in_executor(None, self._pressure_tick)
+            # the registry counts these steps as budget_pressure_steps_total
             if step == "step_down":
-                self._count("budget_steps_down")
+                self.counters["budget_steps_down"] += 1
             elif step == "step_up":
-                self._count("budget_steps_up")
+                self.counters["budget_steps_up"] += 1
 
     def _pressure_tick(self) -> Optional[str]:
         with self._engine_lock:
@@ -426,10 +442,15 @@ class ServeDaemon:
     # ------------------------------------------------------- batching loop
 
     async def _run(self) -> None:
-        while True:
+        stop = False
+        while not stop:
+            st = _STAGES.tick()
+            wait = st.begin("wait")
             req = await self._queue.get()
+            st.end(wait)
             if req is None:
                 return
+            collect = st.begin("collect")
             batch = [req]
             size = req.queries.shape[0]
             t_end = time.monotonic() + self.cfg.batch_window_ms / 1000.0
@@ -442,13 +463,17 @@ class ServeDaemon:
                 except asyncio.TimeoutError:
                     break
                 if nxt is None:
-                    await self._dispatch(batch)
-                    return
+                    stop = True
+                    break
                 batch.append(nxt)
                 size += nxt.queries.shape[0]
-            await self._dispatch(batch)
+            await self._dispatch(batch, st, collect)
+            st.observe()
 
-    async def _dispatch(self, batch: List[_Request]) -> None:
+    async def _dispatch(self, batch: List[_Request], st, collect) -> None:
+        """One tick after collection: expire, hand the live requests'
+        queries to the worker, resolve their futures.  ``collect`` (the
+        stage token from ``_run``) ends at the hand-off."""
         now = time.monotonic()
         live: List[_Request] = []
         for req in batch:
@@ -469,21 +494,26 @@ class ServeDaemon:
                 live.append(req)
         _QUEUE_DEPTH.set(self._queued)
         if not live:
+            st.end(collect)
             return
         q = np.concatenate([r.queries for r in live], axis=0)
         n = int(q.shape[0])
         batch_deadline = min(r.deadline for r in live)
         self._inflight = n
         self._count("batches")
+        st.end(collect)
+        # annotated, so the hand-off there and back shows in a profile as
+        # the part of the tick outside the worker's ``dispatch``
         tick = trace.begin(
             "dispatch_tick", cat="daemon",
             args={"n_requests": len(live), "n_queries": n,
-                  "trace_ids": [r.trace_id for r in live]}) if ON.enabled else None
+                  "trace_ids": [r.trace_id for r in live]},
+            annotate=True) if ON.enabled else None
         loop = asyncio.get_running_loop()
         try:
             t0 = time.monotonic()
-            answers = await loop.run_in_executor(
-                None, self._dispatch_sync, q, batch_deadline)
+            answers, worker_s = await loop.run_in_executor(
+                None, self._dispatch_sync, q, batch_deadline, st)
             dt = time.monotonic() - t0
         except asyncio.CancelledError:
             # kill() cancelled the loop mid-dispatch: the worker thread will
@@ -508,25 +538,27 @@ class ServeDaemon:
             return
         self._inflight = 0
         _DISPATCH_MS.observe(dt * 1000.0)
+        st.add("handoff", (dt - worker_s) * 1000.0)
         trace.end(tick, outcome="answered")
-        inst = n / max(dt, 1e-9)
-        self._rate_qps = (inst if self._rate_qps is None
-                          else 0.7 * self._rate_qps + 0.3 * inst)
-        done = time.monotonic()
-        lo = 0
-        for req in live:
-            hi = lo + req.queries.shape[0]
-            if not req.future.done():   # kill() may have failed it already
-                self._count("answered", hi - lo)
-                lat_s = done - req.t_submit
-                self.latencies.append(lat_s)
-                _REQ_LATENCY.observe(lat_s * 1000.0)
-                if ON.enabled:
-                    trace.event("completed", cat="request",
-                                trace_id=req.trace_id,
-                                latency_ms=round(lat_s * 1000.0, 3))
-                req.future.set_result(answers[lo:hi])
-            lo = hi
+        with st("resolve"):
+            inst = n / max(dt, 1e-9)
+            self._rate_qps = (inst if self._rate_qps is None
+                              else 0.7 * self._rate_qps + 0.3 * inst)
+            done = time.monotonic()
+            lo = 0
+            for req in live:
+                hi = lo + req.queries.shape[0]
+                if not req.future.done():   # kill() may have failed it already
+                    self._count("answered", hi - lo)
+                    lat_s = done - req.t_submit
+                    self.latencies.append(lat_s)
+                    _REQ_LATENCY.observe(lat_s * 1000.0)
+                    if ON.enabled:
+                        trace.event("completed", cat="request",
+                                    trace_id=req.trace_id,
+                                    latency_ms=round(lat_s * 1000.0, 3))
+                    req.future.set_result(answers[lo:hi])
+                lo = hi
 
     def _queue_span(self, req: _Request, now: float, expired: bool) -> None:
         """Retroactive queue-wait span: submit -> the dispatch tick that
@@ -553,10 +585,18 @@ class ServeDaemon:
             return q
         return np.concatenate([q, np.repeat(q[:1], size - n, axis=0)], axis=0)
 
-    def _dispatch_sync(self, q: np.ndarray, deadline: float) -> np.ndarray:
-        """One padded dispatch through breaker + ladder (worker thread)."""
+    def _dispatch_sync(self, q: np.ndarray, deadline: float, st) -> tuple:
+        """One padded dispatch through breaker + ladder (worker thread):
+        (answers, the worker's own seconds, from its first line to its
+        last)."""
+        t0 = time.monotonic()
+        answers = self._serve_padded(q, deadline, st)
+        return answers, time.monotonic() - t0
+
+    def _serve_padded(self, q: np.ndarray, deadline: float, st) -> np.ndarray:
         n = int(q.shape[0])
-        q = self._pad(q)
+        with st("pad"):
+            q = self._pad(q)
         now = time.monotonic()
         if self._publishing and self._publish_pin is not None:
             # pinned-epoch rung: a publish is refreshing the engine right
@@ -582,7 +622,8 @@ class ServeDaemon:
             self._count("device_batches")
             t0 = time.monotonic()
             with trace.span("dispatch", cat="daemon", annotate=True,
-                            args={"rung": "device", "padded": int(q.shape[0])}):
+                            args={"rung": "device", "padded": int(q.shape[0])}
+                            if ON.enabled else None):
                 answers = self._serve(q, self.cfg.backend, deadline)
             dt = time.monotonic() - t0
             # failure signal for the breaker: the engine's ladder downgraded

@@ -37,7 +37,7 @@ import numpy as np
 from repro.ft import inject
 from repro.graph.csr import INVALID
 from repro.obs import metrics, trace
-from repro.obs.state import ON
+from repro.obs.stages import NO_TICK, StageFamily
 from repro.serve.planner import BatchPlan, plan_batch, tier_widths
 from repro.serve.prefilter import apply_prefilters
 
@@ -182,10 +182,16 @@ _M_DEGRADED = metrics.counter(
 _DEGRADED_KIND = {k: _M_DEGRADED.labels(kind=k) for k in _ZERO_DEGRADATION}
 _M_EPOCH = metrics.gauge(
     "engine_epoch", "label-snapshot epoch the engine currently serves")
-_M_UNCERTAIN = metrics.counter(
-    "engine_verdict_uncertain_total",
-    "budget-truncated label misses that could not be proven NO and routed "
-    "to the exact-search rung")
+# one observation per batch of each stage that ran, its tiers summed:
+#   map        original ids -> condensation ids, and the batch's set-up
+#   prefilter  the prefilter stack over the batch
+#   plan       tier plan and tile padding (host)
+#   enqueue    the device calls, one per tier (returns before the device ends)
+#   sync       the blocking copy of the tier results to the host
+#   scatter    the results back into batch order
+_STAGES = StageFamily(
+    "engine_stage_ms", "engine batch time by stage",
+    ("map", "prefilter", "plan", "enqueue", "sync", "scatter"), cat="engine")
 
 
 class QueryEngine:
@@ -500,7 +506,6 @@ class QueryEngine:
                 self.degradation["searched"] += 1
             _DEGRADED_KIND["uncertain"].inc()
             _DEGRADED_KIND["searched"].inc()
-            _M_UNCERTAIN.inc()
             return bool(self._search_batch(np.asarray([[u, v]]))[0])
         return False
 
@@ -521,24 +526,27 @@ class QueryEngine:
         merge (counted as ``deadline_to_host``).  Deadlines never change
         verdicts — every rung stays exact.
         """
-        queries = self._map_ids(np.asarray(queries))
-        queries = np.ascontiguousarray(np.asarray(queries, dtype=np.int32))
-        backend = self.backend if backend is None else select_backend(backend, self.mesh)
-        # capture the budget view ONCE: everything this batch reads (matrices,
-        # masks, widths) comes from one immutable tuple, so a pressure-loop
-        # re-truncation landing mid-batch cannot mix old masks with new rows
-        bv = self._budget_view
-        store = None if bv is None else bv[0]
-        o = self.oracle if store is None else store.oracle
-        out = np.zeros(queries.shape[0], dtype=bool)
-        degraded = dict(_ZERO_DEGRADATION)
+        st = _STAGES.tick()
+        with st("map"):
+            queries = self._map_ids(np.asarray(queries))
+            queries = np.ascontiguousarray(np.asarray(queries, dtype=np.int32))
+            backend = self.backend if backend is None else select_backend(backend, self.mesh)
+            # capture the budget view ONCE: everything this batch reads
+            # (matrices, masks, widths) comes from one immutable tuple, so a
+            # pressure-loop re-truncation landing mid-batch cannot mix old
+            # masks with new rows
+            bv = self._budget_view
+            store = None if bv is None else bv[0]
+            o = self.oracle if store is None else store.oracle
+            out = np.zeros(queries.shape[0], dtype=bool)
+            degraded = dict(_ZERO_DEGRADATION)
+            label_idx = np.arange(queries.shape[0])
 
         # ladder rung 0 (when needed): queries touching quarantined label
         # rows bypass prefilters TOO — length/level prefilters read the very
         # state that failed verification, and a zero-filled out_len would
         # flip verdicts to False.  Everything they need comes from the
         # fallback graph.
-        label_idx = np.arange(queries.shape[0])
         if self.quarantine_out is not None or self.quarantine_in is not None:
             qm = np.zeros(queries.shape[0], dtype=bool)
             if self.quarantine_out is not None:
@@ -552,74 +560,73 @@ class QueryEngine:
                 out[q_idx] = self._search_batch(queries[q_idx])
                 label_idx = np.nonzero(~qm)[0]
 
-        pf = apply_prefilters(queries[label_idx], o.out_len, o.in_len, self.level)
-        out[label_idx] = pf.decided & pf.value
-        rest_idx = label_idx[~pf.decided]
-        # the batch record is LOCAL until the batch finishes: _tally
-        # publishes it (with the counter adds) atomically under _stats_lock,
-        # so a concurrent stats()/reset_stats() never sees a half-built
-        # record or tears a tally mid-batch
-        stats = {
-            "backend": backend,
-            "n_queries": int(queries.shape[0]),
-            "n_prefiltered": int(label_idx.shape[0] - rest_idx.size),
-            "tiers": [],
-            "degraded": degraded,
-        }
-        sp = trace.span("engine.batch", cat="engine", args={
-            "backend": backend, "n": stats["n_queries"],
-            "prefiltered": stats["n_prefiltered"]}) if ON.enabled else trace.NOOP_SPAN
-        with sp:
-            if rest_idx.size:
-                rest = queries[rest_idx]
-
-                if backend == "host":
+        with st("prefilter"):
+            pf = apply_prefilters(queries[label_idx], o.out_len, o.in_len, self.level)
+            out[label_idx] = pf.decided & pf.value
+            rest_idx = label_idx[~pf.decided]
+            rest = queries[rest_idx]
+            # the batch record is LOCAL until the batch finishes: _tally
+            # publishes it (with the counter adds) atomically under
+            # _stats_lock, so a concurrent stats()/reset_stats() never sees a
+            # half-built record or tears a tally mid-batch
+            stats = {
+                "backend": backend,
+                "n_queries": int(queries.shape[0]),
+                "n_prefiltered": int(label_idx.shape[0] - rest_idx.size),
+                "tiers": [],
+                "degraded": degraded,
+            }
+        if rest_idx.size:
+            if backend == "host":
+                res = self._host_batch(rest, o)
+            elif deadline is not None and time.monotonic() > deadline:
+                # past budget before the device attempt: retrace risk is
+                # the one unbounded cost left — take the predictable path
+                degraded["deadline_to_host"] += int(rest.shape[0])
+                trace.event("degrade", cat="engine", kind="deadline_to_host",
+                            n=int(rest.shape[0]))
+                res = self._host_batch(rest, o)
+            else:
+                try:
+                    if backend in ("dense", "kernel"):
+                        res = self._device_batch(
+                            rest, use_kernel=backend == "kernel",
+                            stats=stats, view=bv, st=st)
+                    else:
+                        res = self._sharded_batch(rest, backend, view=bv, st=st)
+                except Exception as e:  # ladder: device failure -> host merge
+                    degraded["device_to_host"] += int(rest.shape[0])
+                    trace.event("degrade", cat="engine", kind="device_to_host",
+                                n=int(rest.shape[0]), error=type(e).__name__)
+                    warnings.warn(
+                        f"{backend!r} backend failed ({type(e).__name__}: {e}); "
+                        f"serving {rest.shape[0]} queries on the host merge path",
+                        stacklevel=2)
                     res = self._host_batch(rest, o)
-                elif deadline is not None and time.monotonic() > deadline:
-                    # past budget before the device attempt: retrace risk is
-                    # the one unbounded cost left — take the predictable path
-                    degraded["deadline_to_host"] += int(rest.shape[0])
-                    sp.event("degrade", kind="deadline_to_host", n=int(rest.shape[0]))
-                    res = self._host_batch(rest, o)
-                else:
-                    try:
-                        if backend in ("dense", "kernel"):
-                            res = self._device_batch(
-                                rest, use_kernel=backend == "kernel",
-                                stats=stats, view=bv)
-                        else:
-                            res = self._sharded_batch(rest, backend, view=bv)
-                    except Exception as e:  # ladder: device failure -> host merge
-                        degraded["device_to_host"] += int(rest.shape[0])
-                        sp.event("degrade", kind="device_to_host",
-                                 n=int(rest.shape[0]), error=type(e).__name__)
-                        warnings.warn(
-                            f"{backend!r} backend failed ({type(e).__name__}: {e}); "
-                            f"serving {rest.shape[0]} queries on the host merge path",
-                            stacklevel=2)
-                        res = self._host_batch(rest, o)
+            with st("scatter"):
                 out[rest_idx] = res
 
-            # three-valued epilogue: under a budget, a False verdict from the
-            # labels (backend miss OR emptiness prefilter on a cut-to-empty
-            # row) is only proven when at most one row was truncated.  The
-            # same-vertex and topo-level prefilters are graph facts, exact at
-            # any budget, so they keep their verdicts.
-            if store is not None and store.any_truncated and label_idx.size:
-                lq = queries[label_idx]
-                unc = (store.truncated_out[lq[:, 0]]
-                       & store.truncated_in[lq[:, 1]] & ~out[label_idx])
-                unc &= lq[:, 0] != lq[:, 1]
-                if self.level is not None:
-                    unc &= self.level[lq[:, 0]] < self.level[lq[:, 1]]
-                unc_idx = label_idx[unc]
-                if unc_idx.size:
-                    degraded["uncertain"] += int(unc_idx.size)
-                    degraded["searched"] += int(unc_idx.size)
-                    sp.event("degrade", kind="uncertain", n=int(unc_idx.size))
-                    out[unc_idx] = self._search_batch(queries[unc_idx])
-            self._tally(stats, degraded)
-            return out
+        # three-valued epilogue: under a budget, a False verdict from the
+        # labels (backend miss OR emptiness prefilter on a cut-to-empty
+        # row) is only proven when at most one row was truncated.  The
+        # same-vertex and topo-level prefilters are graph facts, exact at
+        # any budget, so they keep their verdicts.
+        if store is not None and store.any_truncated and label_idx.size:
+            lq = queries[label_idx]
+            unc = (store.truncated_out[lq[:, 0]]
+                   & store.truncated_in[lq[:, 1]] & ~out[label_idx])
+            unc &= lq[:, 0] != lq[:, 1]
+            if self.level is not None:
+                unc &= self.level[lq[:, 0]] < self.level[lq[:, 1]]
+            unc_idx = label_idx[unc]
+            if unc_idx.size:
+                degraded["uncertain"] += int(unc_idx.size)
+                degraded["searched"] += int(unc_idx.size)
+                trace.event("degrade", cat="engine", kind="uncertain", n=int(unc_idx.size))
+                out[unc_idx] = self._search_batch(queries[unc_idx])
+        self._tally(stats, degraded)
+        st.observe()
+        return out
 
     def warmup(self, max_batch: int, backend: Optional[str] = None) -> int:
         """Compile every device program a batch of up to ``max_batch``
@@ -676,45 +683,45 @@ class QueryEngine:
         for k, v in degraded.items():
             if v:
                 _DEGRADED_KIND[k].inc(v)
-        if degraded.get("uncertain"):
-            _M_UNCERTAIN.inc(degraded["uncertain"])
 
     # ------------------------------------------------------------ backends
 
     def _device_batch(self, rest: np.ndarray, use_kernel: bool,
                       stats: Optional[dict] = None,
-                      view: Optional[tuple] = None) -> np.ndarray:
-        # chaos hook: an injected device failure here exercises the ladder's
-        # device -> host downgrade in query_batch
-        inject.fire("serve.device_dispatch", backend="kernel" if use_kernel else "dense")
+                      view: Optional[tuple] = None, st=NO_TICK) -> np.ndarray:
         if stats is None:
             stats = {"tiers": []}   # direct callers outside query_batch
         if view is not None:
             o, lo, li, widths = view[0].oracle, view[1], view[2], view[3]
         else:
             o, lo, li, widths = self.oracle, self._lo, self._li, self.widths
+        # chaos hook (inside enqueue): an injected device failure exercises
+        # the ladder's device -> host downgrade in query_batch
+        site = "kernel" if use_kernel else "dense"
         if not self.bucketing:
-            with trace.span("device_call", cat="device", annotate=True,
-                            args={"rows": int(rest.shape[0])} if ON.enabled else None):
+            with st("enqueue"):
+                inject.fire("serve.device_dispatch", backend=site)
                 r = serve_step(lo, li, jnp.asarray(rest), use_kernel=use_kernel)
-            return np.asarray(r)
-        plan = plan_batch(rest, o.out_len, o.in_len, widths, min_tile=self.min_tile)
-        results = []
-        for tier in plan.tiers:
-            q = jnp.asarray(plan.padded_queries(rest, tier))
-            with trace.span("device_call", cat="device", annotate=True,
-                            args={"width": tier.width, "rows": tier.rows}
-                            if ON.enabled else None):
-                results.append(
-                    _tier_intersect(lo, li, q, tier.width, use_kernel))
-            stats["tiers"].append(
+            with st("sync"):
+                return np.asarray(r)
+        with st("plan"):
+            plan = plan_batch(rest, o.out_len, o.in_len, widths, min_tile=self.min_tile)
+            padded = [plan.padded_queries(rest, tier) for tier in plan.tiers]
+        with st("enqueue"):
+            inject.fire("serve.device_dispatch", backend=site)
+            results = [_tier_intersect(lo, li, jnp.asarray(q), tier.width, use_kernel)
+                       for q, tier in zip(padded, plan.tiers)]
+        with st("sync"):
+            host = [np.asarray(r) for r in results]
+        with st("scatter"):
+            out = plan.scatter(host)
+            stats["tiers"].extend(
                 {"width": tier.width, "count": int(tier.idx.size), "rows": tier.rows}
-            )
-        return plan.scatter([np.asarray(r) for r in results])
+                for tier in plan.tiers)
+        return out
 
     def _sharded_batch(self, rest: np.ndarray, backend: str,
-                       view: Optional[tuple] = None) -> np.ndarray:
-        inject.fire("serve.device_dispatch", backend=backend)
+                       view: Optional[tuple] = None, st=NO_TICK) -> np.ndarray:
         lo, li = (self._lo, self._li) if view is None else (view[1], view[2])
         made = self._sharded_fns.get(backend)
         if made is None:
@@ -742,5 +749,8 @@ class QueryEngine:
         pad = (-B) % max(shards, 1)
         if pad:
             rest = np.concatenate([rest, np.zeros((pad, 2), dtype=rest.dtype)], axis=0)
-        res = np.asarray(fn(lo, li, jnp.asarray(rest)))
-        return res[:B]
+        with st("enqueue"):
+            inject.fire("serve.device_dispatch", backend=backend)
+            r = fn(lo, li, jnp.asarray(rest))
+        with st("sync"):
+            return np.asarray(r)[:B]

@@ -144,7 +144,8 @@ def test_chrome_payload_structure_and_ring_bound(tmp_path):
     tr.end(tok, outcome="done")
     payload = tr.chrome_payload(meta={"k": "v"})
     assert payload["displayTimeUnit"] == "ms"
-    assert payload["metadata"] == {"k": "v"}
+    # the metadata names the ring's clock beside the caller's own keys
+    assert payload["metadata"] == {"k": "v", "clock": trace.CLOCK}
     evs = payload["traceEvents"]
     assert [e["ts"] for e in evs] == sorted(e["ts"] for e in evs)
     outer = next(e for e in evs if e["name"] == "outer")
