@@ -120,7 +120,7 @@ def one_chip(args) -> None:
     from repro.core.api import build_oracle
     from repro.graph.scc import condense_to_dag
     from repro.serve.daemon import DaemonConfig
-    from repro.serve.engine import _tier_intersect
+    from repro.serve.engine import _tier_intersect_fused
     from repro.serve.openloop import run_open_loop
 
     g = make_graph(args.scale, args.seed)
@@ -147,10 +147,12 @@ def one_chip(args) -> None:
     log("label_matrix_bytes", label_bytes)
     log("tier_widths", co.engine.widths)
 
-    # the tier program the engine dispatches must hold the Pallas kernel
+    # the program the engine dispatches (fused: the store is narrow) must
+    # hold the Pallas kernel
     lo, li = co.oracle.device_labels()
-    hlo = _tier_intersect.lower(lo, li, jax.numpy.zeros((256, 2), jax.numpy.int32),
-                                width=co.engine.widths[-1], use_kernel=True).compile().as_text()
+    hlo = _tier_intersect_fused.lower(
+        lo, li, co.engine._vertex_meta(co.oracle), jax.numpy.zeros((256, 2), jax.numpy.int32),
+        use_kernel=True).compile().as_text()
     require("tpu_custom_call" in hlo, "compiled tier program holds no tpu_custom_call")
     log("tier_program_has_tpu_custom_call", True)
 

@@ -9,6 +9,11 @@ engine owns the serving pipeline:
             -> backend intersection
             -> scatter back
 
+On the bucketed dense/kernel backends a store no wider than
+``FUSED_MAX_WIDTH`` slots skips the host prefilter and the tier plan: one
+fused device program per batch prefilters, gathers full-width rows and
+intersects them, and returns one small code per row.
+
 Backends:
   host         per-query sorted merge on the CPU (searchsorted + rank-ordered
                early exit; the reference path)
@@ -38,10 +43,22 @@ from repro.ft import inject
 from repro.graph.csr import INVALID
 from repro.obs import metrics, trace
 from repro.obs.stages import NO_TICK, StageFamily
-from repro.serve.planner import BatchPlan, plan_batch, tier_widths
-from repro.serve.prefilter import apply_prefilters
+from repro.serve.planner import plan_batch, tier_widths, whole_batch_plan
+from repro.serve.prefilter import apply_prefilters, prefilter_pairs
 
 BACKENDS = ("host", "dense", "kernel", "sharded", "sharded_hop")
+
+# The fused program compares every row at the store's full padded width.  Up
+# to one lane row, 128 slots, that is cheap: a 4,096-row batch is 4,096 x 128
+# x 128 = 67 M compares on the device, where the host prefilter and tier plan
+# it replaces cost the host more than the device's whole share of a batch.
+# Wider stores keep the tier plan, which spares their short rows the compares
+# of the widest width squared.
+FUSED_MAX_WIDTH = 128
+
+# the fused program's code for each row
+_HIT = 1        # u reaches v
+_DECIDED = 2    # the prefilter decided the row (``engine_prefiltered_total``)
 
 
 def select_backend(name: Optional[str] = None, mesh=None) -> str:
@@ -89,10 +106,9 @@ def serve_step(
     return intersect_rows(a, b)
 
 
-@partial(jax.jit, static_argnames=("width", "use_kernel"))
-def _tier_intersect(L_out, L_in, queries, width: int, use_kernel: bool):
-    """Gather + truncate to the tier width + intersect. One trace per
-    (tile rows, width, backend) triple."""
+def _gather_intersect(L_out, L_in, queries, width: Optional[int], use_kernel: bool):
+    """Gather both label rows of each query, cut to ``width`` columns (None:
+    the full padded width), and intersect them with the backend's compare."""
     a = jnp.take(L_out, queries[:, 0], axis=0)[:, :width]
     b = jnp.take(L_in, queries[:, 1], axis=0)[:, :width]
     if use_kernel:
@@ -100,6 +116,31 @@ def _tier_intersect(L_out, L_in, queries, width: int, use_kernel: bool):
 
         return label_intersect(a, b)
     return intersect_rows(a, b)
+
+
+@partial(jax.jit, static_argnames=("width", "use_kernel"))
+def _tier_intersect(L_out, L_in, queries, width: int, use_kernel: bool):
+    """Gather + truncate to the tier width + intersect. One trace per
+    (tile rows, width, backend) triple."""
+    return _gather_intersect(L_out, L_in, queries, width, use_kernel)
+
+
+@partial(jax.jit, static_argnames=("use_kernel",))
+def _tier_intersect_fused(L_out, L_in, meta, queries, use_kernel: bool):
+    """The whole batch in one program: the prefilters over each pair's row
+    of ``meta`` (``_vertex_meta``), then every row gathered at the store's
+    full width and intersected.  Returns uint8[B] codes, ``_HIT`` for a
+    reachable pair plus ``_DECIDED`` where the prefilter decided it.  One
+    trace per (tile rows, store shape, backend)."""
+    u, v = queries[:, 0], queries[:, 1]
+    # one row gather per side: four 1-D gathers of the lengths and levels
+    # took most of the device time on a v5e
+    mu, mv = jnp.take(meta, u, axis=0), jnp.take(meta, v, axis=0)
+    levels = (mu[:, 2], mv[:, 2]) if meta.shape[1] > 2 else ()
+    pf = prefilter_pairs(u == v, mu[:, 0], mv[:, 1], *levels)
+    hit = _gather_intersect(L_out, L_in, queries, None, use_kernel)
+    verdict = jnp.where(pf.decided, pf.value, hit)
+    return (verdict.astype(jnp.uint8) * _HIT) | (pf.decided.astype(jnp.uint8) * _DECIDED)
 
 
 # ------------------------------------------------------------ sharded modes
@@ -182,13 +223,19 @@ _M_DEGRADED = metrics.counter(
 _DEGRADED_KIND = {k: _M_DEGRADED.labels(kind=k) for k in _ZERO_DEGRADATION}
 _M_EPOCH = metrics.gauge(
     "engine_epoch", "label-snapshot epoch the engine currently serves")
+_M_DEVICE_BATCHES = metrics.counter(
+    "engine_device_batches_total",
+    "batches the bucketed dense/kernel backends served on the device, by path",
+    labelnames=("path",))
+_DEVICE_PATH = {p: _M_DEVICE_BATCHES.labels(path=p) for p in ("fused", "tiered")}
 # one observation per batch of each stage that ran, its tiers summed:
 #   map        original ids -> condensation ids, and the batch's set-up
-#   prefilter  the prefilter stack over the batch
-#   plan       tier plan and tile padding (host)
-#   enqueue    the device calls, one per tier (returns before the device ends)
-#   sync       the blocking copy of the tier results to the host
-#   scatter    the results back into batch order
+#   prefilter  the prefilter stack over the batch (host; not on the fused path)
+#   plan       tier plan and tile padding (host; not on the fused path)
+#   enqueue    the device calls, one per tier or one fused program, with the
+#              query upload (returns before the device ends)
+#   sync       the blocking copy of the results to the host
+#   scatter    the results back into batch order (the fused codes decoded)
 _STAGES = StageFamily(
     "engine_stage_ms", "engine batch time by stage",
     ("map", "prefilter", "plan", "enqueue", "sync", "scatter"), cat="engine")
@@ -284,7 +331,7 @@ class QueryEngine:
         self.model_axis = model_axis
         self.comp_source = comp_source
         self.epoch = int(epoch)
-        self._lo, self._li = self._place_labels(oracle)
+        self._lo, self._li, self._meta = self._place_labels(oracle)
         self.widths = tier_widths(
             oracle.out_len, oracle.in_len, oracle.max_label_len, n_tiers=n_tiers
         )
@@ -306,24 +353,35 @@ class QueryEngine:
         # node cap for the search rung (None = unbounded; the search stays
         # exact either way — exhaustion falls back to forward-only BFS)
         self.search_node_budget = search_node_budget
-        # (store, device L_out, device L_in, tier widths) — swapped whole in
-        # set_budget so a batch's entry-time capture is internally consistent
+        # (store, device L_out, device L_in, tier widths, device vertex meta)
+        # — swapped whole in set_budget so a batch's entry-time capture is
+        # internally consistent
         self._budget_view: Optional[tuple] = None
 
     def _place_labels(self, oracle):
-        """Device label matrices laid out for the default backend; every
-        upload (full, refreshed or budget-truncated labels) goes through
-        here.  The mesh backends upload straight from the host arrays into
-        their sharding (replicated, or split along the hop dim over the
-        model axis), so no full copy ever lands on one device."""
+        """Device label matrices laid out for the default backend, and the
+        per-vertex rows the fused program's prefilter reads
+        (``_vertex_meta``); every upload (full, refreshed or budget-truncated
+        labels) goes through here, so no batch uploads anything but its
+        queries.  The mesh backends upload the matrices straight from the
+        host arrays into their sharding (replicated, or split along the hop
+        dim over the model axis), so no full copy ever lands on one
+        device."""
+        meta = self._vertex_meta(oracle)
         if self.backend not in ("sharded", "sharded_hop"):
-            return oracle.device_labels()
+            return (*oracle.device_labels(), meta)
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         spec = P(None, self.model_axis) if self.backend == "sharded_hop" else P()
         sharding = NamedSharding(self.mesh, spec)
         return (jax.device_put(oracle.L_out, sharding),
-                jax.device_put(oracle.L_in, sharding))
+                jax.device_put(oracle.L_in, sharding), meta)
+
+    def _vertex_meta(self, oracle):
+        """int32[n, 3] on the device, one row per vertex: ``out_len``,
+        ``in_len``, ``level`` (int32[n, 2] without levels)."""
+        cols = [oracle.out_len, oracle.in_len] + ([] if self.level is None else [self.level])
+        return jnp.asarray(np.stack(cols, axis=1).astype(np.int32))
 
     # ---------------------------------------------------------- publishing
 
@@ -341,7 +399,7 @@ class QueryEngine:
         self.oracle = oracle
         if level is not None:
             self.level = np.array(level, dtype=np.int32)  # copy: see __init__
-        self._lo, self._li = self._place_labels(oracle)
+        self._lo, self._li, self._meta = self._place_labels(oracle)
         self.widths = tier_widths(
             oracle.out_len, oracle.in_len, oracle.max_label_len, n_tiers=self.n_tiers
         )
@@ -423,8 +481,9 @@ class QueryEngine:
 
         The engine keeps serving ``self.oracle``'s graph — only the label
         MATRICES read by the intersection backends switch to the truncated
-        store, together with its truncation masks and a tier-width plan fit
-        to the truncated length distribution.  All four swap as one tuple:
+        store, together with its truncation masks, a tier-width plan fit
+        to the truncated length distribution and the truncated lengths'
+        device rows.  All five swap as one tuple:
         an in-flight batch that captured the previous view stays internally
         consistent (see class docstring), which is what lets the daemon's
         pressure loop re-truncate between dispatches without draining."""
@@ -432,10 +491,10 @@ class QueryEngine:
             self._budget_view = None
             return
         t = store.oracle
-        lo, li = self._place_labels(t)
+        lo, li, meta = self._place_labels(t)
         widths = tier_widths(t.out_len, t.in_len, t.max_label_len,
                              n_tiers=self.n_tiers)
-        self._budget_view = (store, lo, li, widths)
+        self._budget_view = (store, lo, li, widths, meta)
 
     def _fallback(self):
         """Resolve the fallback graph to a cached (g, g_rev) pair."""
@@ -537,7 +596,6 @@ class QueryEngine:
             # masks with new rows
             bv = self._budget_view
             store = None if bv is None else bv[0]
-            o = self.oracle if store is None else store.oracle
             out = np.zeros(queries.shape[0], dtype=bool)
             degraded = dict(_ZERO_DEGRADATION)
             label_idx = np.arange(queries.shape[0])
@@ -560,51 +618,31 @@ class QueryEngine:
                 out[q_idx] = self._search_batch(queries[q_idx])
                 label_idx = np.nonzero(~qm)[0]
 
-        with st("prefilter"):
-            pf = apply_prefilters(queries[label_idx], o.out_len, o.in_len, self.level)
-            out[label_idx] = pf.decided & pf.value
-            rest_idx = label_idx[~pf.decided]
-            rest = queries[rest_idx]
-            # the batch record is LOCAL until the batch finishes: _tally
-            # publishes it (with the counter adds) atomically under
-            # _stats_lock, so a concurrent stats()/reset_stats() never sees a
-            # half-built record or tears a tally mid-batch
-            stats = {
-                "backend": backend,
-                "n_queries": int(queries.shape[0]),
-                "n_prefiltered": int(label_idx.shape[0] - rest_idx.size),
-                "tiers": [],
-                "degraded": degraded,
-            }
-        if rest_idx.size:
-            if backend == "host":
-                res = self._host_batch(rest, o)
-            elif deadline is not None and time.monotonic() > deadline:
-                # past budget before the device attempt: retrace risk is
-                # the one unbounded cost left — take the predictable path
-                degraded["deadline_to_host"] += int(rest.shape[0])
-                trace.event("degrade", cat="engine", kind="deadline_to_host",
-                            n=int(rest.shape[0]))
-                res = self._host_batch(rest, o)
-            else:
-                try:
-                    if backend in ("dense", "kernel"):
-                        res = self._device_batch(
-                            rest, use_kernel=backend == "kernel",
-                            stats=stats, view=bv, st=st)
-                    else:
-                        res = self._sharded_batch(rest, backend, view=bv, st=st)
-                except Exception as e:  # ladder: device failure -> host merge
-                    degraded["device_to_host"] += int(rest.shape[0])
-                    trace.event("degrade", cat="engine", kind="device_to_host",
-                                n=int(rest.shape[0]), error=type(e).__name__)
-                    warnings.warn(
-                        f"{backend!r} backend failed ({type(e).__name__}: {e}); "
-                        f"serving {rest.shape[0]} queries on the host merge path",
-                        stacklevel=2)
-                    res = self._host_batch(rest, o)
-            with st("scatter"):
-                out[rest_idx] = res
+        # the batch record is LOCAL until the batch finishes: _tally
+        # publishes it (with the counter adds) atomically under _stats_lock,
+        # so a concurrent stats()/reset_stats() never sees a half-built
+        # record or tears a tally mid-batch
+        stats = {
+            "backend": backend,
+            "n_queries": int(queries.shape[0]),
+            "n_prefiltered": 0,
+            "path": None,       # "fused" / "tiered": the device path that served
+            "tiers": [],
+            "degraded": degraded,
+        }
+        fused_error = None
+        if (label_idx.size and self._fuses(backend, bv)
+                and (deadline is None or time.monotonic() <= deadline)):
+            try:
+                res = self._device_batch(queries[label_idx], backend == "kernel",
+                                         stats=stats, view=bv, st=st, fused=True)
+                with st("scatter"):
+                    out[label_idx] = res
+            except Exception as e:  # ladder: the host prefilter + merge below
+                fused_error = e
+        if stats["path"] != "fused":
+            self._host_prefilter_batch(queries, label_idx, out, backend, deadline,
+                                       fused_error, stats=stats, view=bv, st=st)
 
         # three-valued epilogue: under a budget, a False verdict from the
         # labels (backend miss OR emptiness prefilter on a cut-to-empty
@@ -628,10 +666,64 @@ class QueryEngine:
         st.observe()
         return out
 
+    def _host_prefilter_batch(self, queries, label_idx, out, backend, deadline,
+                              fused_error, stats, view, st) -> None:
+        """The label rows of a batch the fused program did not serve: the
+        prefilter on the host, then the rest on the backend, or on the host
+        merge where the backend is ``host``, the device failed
+        (``fused_error``, or a failure here) or the deadline has passed."""
+        o = self.oracle if view is None else view[0].oracle
+        with st("prefilter"):
+            pf = apply_prefilters(queries[label_idx], o.out_len, o.in_len, self.level)
+            out[label_idx] = pf.decided & pf.value
+            rest_idx = label_idx[~pf.decided]
+            rest = queries[rest_idx]
+            stats["n_prefiltered"] = int(label_idx.shape[0] - rest_idx.size)
+        if not rest_idx.size:
+            return
+        degraded = stats["degraded"]
+        if backend == "host":
+            res = self._host_batch(rest, o)
+        elif fused_error is not None:
+            res = self._device_to_host(rest, o, backend, fused_error, degraded)
+        elif deadline is not None and time.monotonic() > deadline:
+            # past budget before the device attempt: retrace risk is
+            # the one unbounded cost left — take the predictable path
+            degraded["deadline_to_host"] += int(rest.shape[0])
+            trace.event("degrade", cat="engine", kind="deadline_to_host",
+                        n=int(rest.shape[0]))
+            res = self._host_batch(rest, o)
+        else:
+            try:
+                if backend in ("dense", "kernel"):
+                    res = self._device_batch(
+                        rest, use_kernel=backend == "kernel",
+                        stats=stats, view=view, st=st)
+                else:
+                    res = self._sharded_batch(rest, backend, view=view, st=st)
+            except Exception as e:  # ladder: device failure -> host merge
+                res = self._device_to_host(rest, o, backend, e, degraded)
+        with st("scatter"):
+            out[rest_idx] = res
+
+    def _device_to_host(self, rest: np.ndarray, o, backend: str, error: Exception,
+                        degraded: dict) -> np.ndarray:
+        """The ladder's device -> host rung: count it, say why, and serve
+        ``rest`` on the host merge path."""
+        degraded["device_to_host"] += int(rest.shape[0])
+        trace.event("degrade", cat="engine", kind="device_to_host",
+                    n=int(rest.shape[0]), error=type(error).__name__)
+        warnings.warn(
+            f"{backend!r} backend failed ({type(error).__name__}: {error}); "
+            f"serving {rest.shape[0]} queries on the host merge path",
+            stacklevel=4)
+        return self._host_batch(rest, o)
+
     def warmup(self, max_batch: int, backend: Optional[str] = None) -> int:
         """Compile every device program a batch of up to ``max_batch``
-        queries can dispatch: each (tier width, power-of-two tile) pair of
-        the planner, or each daemon pad size without bucketing.
+        queries can dispatch: the fused program at each power-of-two tile,
+        or each (tier width, tile) pair of the planner for a store too wide
+        to fuse, or each daemon pad size without bucketing.
 
         Runs OUTSIDE the degradation ladder: a device program that fails
         here is a fault in the program, not a runtime device fault, so it
@@ -641,8 +733,8 @@ class QueryEngine:
         if backend == "host":
             return 0
         bv = self._budget_view
-        lo, li, widths = ((self._lo, self._li, self.widths) if bv is None
-                          else (bv[1], bv[2], bv[3]))
+        lo, li, widths, meta = ((self._lo, self._li, self.widths, self._meta)
+                                if bv is None else bv[1:5])
 
         def ladder(lo_rows: int) -> list:  # powers of two up to max_batch
             sizes = [lo_rows]
@@ -651,7 +743,12 @@ class QueryEngine:
             return sizes
 
         use_kernel = backend == "kernel"
-        if backend in ("dense", "kernel") and self.bucketing:
+        if self._fuses(backend, bv):
+            # a batch pads to a power of two from min_tile
+            runs = [_tier_intersect_fused(lo, li, meta, jnp.zeros((r, 2), jnp.int32),
+                                          use_kernel)
+                    for r in ladder(self.min_tile)]
+        elif backend in ("dense", "kernel") and self.bucketing:
             # the planner pads each tier to a power of two from min_tile
             runs = [_tier_intersect(lo, li, jnp.zeros((r, 2), jnp.int32), w, use_kernel)
                     for r in ladder(self.min_tile) for w in widths]
@@ -680,21 +777,39 @@ class QueryEngine:
             self.last_stats = stats
         _M_QUERIES.inc(stats["n_queries"])
         _M_PREFILTERED.inc(stats["n_prefiltered"])
+        if stats["path"] is not None:
+            _DEVICE_PATH[stats["path"]].inc()
         for k, v in degraded.items():
             if v:
                 _DEGRADED_KIND[k].inc(v)
 
     # ------------------------------------------------------------ backends
 
+    def _fuses(self, backend: str, view: Optional[tuple]) -> bool:
+        """Whether the fused program serves a batch of this backend on this
+        captured view: bucketed dense/kernel, store no wider than
+        ``FUSED_MAX_WIDTH`` slots."""
+        lo, li = (self._lo, self._li) if view is None else view[1:3]
+        return (backend in ("dense", "kernel") and self.bucketing
+                and max(lo.shape[1], li.shape[1]) <= FUSED_MAX_WIDTH)
+
     def _device_batch(self, rest: np.ndarray, use_kernel: bool,
                       stats: Optional[dict] = None,
-                      view: Optional[tuple] = None, st=NO_TICK) -> np.ndarray:
+                      view: Optional[tuple] = None, st=NO_TICK,
+                      fused: bool = False) -> np.ndarray:
+        """Verdicts for ``rest`` from the device.  With ``fused``, ``rest`` is
+        every label row of the batch, unfiltered: one fused program
+        prefilters and intersects it at the store's full width (a one-tier
+        plan), and its prefiltered count lands in ``stats``.  Otherwise
+        ``rest`` is what the host prefilter left, one program per tier (or
+        one at the full width without bucketing)."""
         if stats is None:
             stats = {"tiers": []}   # direct callers outside query_batch
         if view is not None:
-            o, lo, li, widths = view[0].oracle, view[1], view[2], view[3]
+            o, lo, li, widths, meta = view[0].oracle, *view[1:5]
         else:
-            o, lo, li, widths = self.oracle, self._lo, self._li, self.widths
+            o, lo, li, widths, meta = (self.oracle, self._lo, self._li, self.widths,
+                                       self._meta)
         # chaos hook (inside enqueue): an injected device failure exercises
         # the ladder's device -> host downgrade in query_batch
         site = "kernel" if use_kernel else "dense"
@@ -704,20 +819,33 @@ class QueryEngine:
                 r = serve_step(lo, li, jnp.asarray(rest), use_kernel=use_kernel)
             with st("sync"):
                 return np.asarray(r)
-        with st("plan"):
-            plan = plan_batch(rest, o.out_len, o.in_len, widths, min_tile=self.min_tile)
-            padded = [plan.padded_queries(rest, tier) for tier in plan.tiers]
-        with st("enqueue"):
-            inject.fire("serve.device_dispatch", backend=site)
-            results = [_tier_intersect(lo, li, jnp.asarray(q), tier.width, use_kernel)
-                       for q, tier in zip(padded, plan.tiers)]
+        if fused:
+            with st("enqueue"):
+                plan = whole_batch_plan(rest.shape[0], max(lo.shape[1], li.shape[1]),
+                                        min_tile=self.min_tile)
+                q = plan.padded_queries(rest, plan.tiers[0])
+                inject.fire("serve.device_dispatch", backend=site)
+                results = [_tier_intersect_fused(lo, li, meta, jnp.asarray(q), use_kernel)]
+        else:
+            with st("plan"):
+                plan = plan_batch(rest, o.out_len, o.in_len, widths, min_tile=self.min_tile)
+                padded = [plan.padded_queries(rest, tier) for tier in plan.tiers]
+            with st("enqueue"):
+                inject.fire("serve.device_dispatch", backend=site)
+                results = [_tier_intersect(lo, li, jnp.asarray(q), tier.width, use_kernel)
+                           for q, tier in zip(padded, plan.tiers)]
         with st("sync"):
             host = [np.asarray(r) for r in results]
         with st("scatter"):
+            if fused:   # decode; the pad rows' codes are dropped here
+                codes = host[0][: rest.shape[0]]
+                stats["n_prefiltered"] = int(np.count_nonzero(codes & _DECIDED))
+                host = [codes & _HIT]
             out = plan.scatter(host)
             stats["tiers"].extend(
                 {"width": tier.width, "count": int(tier.idx.size), "rows": tier.rows}
                 for tier in plan.tiers)
+            stats["path"] = "fused" if fused else "tiered"
         return out
 
     def _sharded_batch(self, rest: np.ndarray, backend: str,

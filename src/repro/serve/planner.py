@@ -32,6 +32,12 @@ def _pow2_at_least(x: int) -> int:
     return 1 << max(int(x) - 1, 0).bit_length()
 
 
+def tile_rows(k: int, min_tile: int) -> int:
+    """Rows of the power-of-two tile, at least ``min_tile``, that holds ``k``
+    queries: the shapes the device programs are compiled for."""
+    return _pow2_at_least(max(int(k), int(min_tile)))
+
+
 def tier_widths(
     out_len: np.ndarray,
     in_len: np.ndarray,
@@ -104,6 +110,14 @@ def plan_batch(
         idx = np.nonzero(tier_of == t)[0].astype(np.int32)
         if idx.size == 0:
             continue
-        rows = _pow2_at_least(max(int(idx.size), min_tile))
-        tiers.append(TierPlan(idx=idx, width=int(w), rows=rows))
+        tiers.append(TierPlan(idx=idx, width=int(w), rows=tile_rows(idx.size, min_tile)))
     return BatchPlan(tiers=tiers, n_queries=int(queries.shape[0]))
+
+
+def whole_batch_plan(n_queries: int, width: int, min_tile: int = 256) -> BatchPlan:
+    """Every query in one tier at ``width``: the plan of the engine's fused
+    program, which reads no label lengths on the host."""
+    idx = np.arange(n_queries, dtype=np.int32)
+    return BatchPlan(tiers=[TierPlan(idx=idx, width=int(width),
+                                     rows=tile_rows(n_queries, min_tile))],
+                     n_queries=int(n_queries))

@@ -55,9 +55,17 @@ def apply_prefilters(queries, out_len, in_len, level=None) -> PrefilterResult:
     Works on numpy and jnp inputs alike.
     """
     u, v = queries[:, 0], queries[:, 1]
-    same = u == v
-    dead = (out_len[u] == 0) | (in_len[v] == 0)
-    if level is not None:
-        dead = dead | (level[u] >= level[v])
+    levels = () if level is None else (level[u], level[v])
+    return prefilter_pairs(u == v, out_len[u], in_len[v], *levels)
+
+
+def prefilter_pairs(same, out_len_u, in_len_v, level_u=None, level_v=None) -> PrefilterResult:
+    """The filters over each pair's own values, already gathered: ``same``
+    is u == v, the rest are out_len[u], in_len[v] and, optionally, level[u]
+    and level[v].  The serve path's fused device program gathers them
+    in one row per vertex."""
+    dead = (out_len_u == 0) | (in_len_v == 0)
+    if level_u is not None:
+        dead = dead | (level_u >= level_v)
     # `same` wins over `dead` (level[u] >= level[v] always holds for u == v)
     return PrefilterResult(decided=same | dead, value=same)

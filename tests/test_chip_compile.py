@@ -62,3 +62,20 @@ def test_frontier_or_compiles_for_v5e(one_chip, rows, slots):
                one_chip, ((rows, slots), jnp.int32),
                ((CITESEER_N, FRONTIER_WORDS), jnp.uint32))
     assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("use_kernel", [True, False])
+def test_fused_serve_program_compiles_for_v5e(one_chip, monkeypatch, use_kernel):
+    """The serve path's fused program (prefilter, full-width gather,
+    intersect) at the citeseer store's shape and the largest batch tile."""
+    from repro.serve.engine import _tier_intersect_fused
+
+    # the kernel, not its interpreter: ``label_intersect`` picks it while
+    # tracing, so traces made on the CPU earlier in this process must go
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    jax.clear_caches()
+    n, width, rows = CITESEER_N, 16, 4096
+    hlo = _hlo(lambda lo, li, meta, q: _tier_intersect_fused(lo, li, meta, q, use_kernel),
+               one_chip, ((n, width), jnp.int32), ((n, width // 2), jnp.int32),
+               ((n, 3), jnp.int32), ((rows, 2), jnp.int32))
+    assert ("tpu_custom_call" in hlo) == use_kernel

@@ -335,7 +335,14 @@ def test_health_surfaces_breaker_and_degradation(co, rng):
 # ------------------------------------------------------------- warm-up
 
 
-def test_engine_warmup_runs_every_tier_shape(co):
+@pytest.mark.parametrize("path", ["fused", "tiered"])
+def test_engine_warmup_runs_every_tier_shape(co, path, monkeypatch):
+    """The fused program at every tile, or, for a store too wide to fuse,
+    every (tile, tier width) program."""
+    from repro.serve import engine as engine_mod
+
+    if path == "tiered":
+        monkeypatch.setattr(engine_mod, "FUSED_MAX_WIDTH", 0)
     eng = co.engine
     before = dict(eng.degradation)
     rows = 0
@@ -345,14 +352,18 @@ def test_engine_warmup_runs_every_tier_shape(co):
         if size >= 512:
             break
         size *= 2
-    assert eng.warmup(512, backend="dense") == rows * len(eng.widths)
+    per_tile = 1 if path == "fused" else len(eng.widths)
+    assert eng.warmup(512, backend="dense") == rows * per_tile
     assert eng.warmup(512, backend="host") == 0
     assert eng.degradation == before
 
 
-def test_open_loop_warmup_raises_device_program_faults(co, monkeypatch):
+@pytest.mark.parametrize("program", ["_tier_intersect_fused", "_tier_intersect"])
+def test_open_loop_warmup_raises_device_program_faults(co, monkeypatch, program):
     """A device program that fails before the clock starts is a fault in
-    the program: it raises, instead of being served on the host ladder."""
+    the program: it raises, instead of being served on the host ladder.
+    The fused program serves this narrow store; the tier programs serve it
+    once the width limit is below it."""
     from repro.serve import engine as engine_mod
     from repro.serve.openloop import run_open_loop
 
@@ -360,7 +371,9 @@ def test_open_loop_warmup_raises_device_program_faults(co, monkeypatch):
         raise RuntimeError("tier program failed to lower")
 
     before = dict(co.engine.degradation)
-    monkeypatch.setattr(engine_mod, "_tier_intersect", broken)
+    if program == "_tier_intersect":
+        monkeypatch.setattr(engine_mod, "FUSED_MAX_WIDTH", 0)
+    monkeypatch.setattr(engine_mod, program, broken)
     with pytest.raises(RuntimeError, match="failed to lower"):
         run_open_loop(co, G, duration_s=0.2,
                       config=DaemonConfig(backend="dense", max_batch=256))

@@ -214,3 +214,180 @@ print('SHARDED_ENGINE_OK')
         capture_output=True, text=True, timeout=1200, env=env, cwd=repo,
     )
     assert "SHARDED_ENGINE_OK" in proc.stdout, proc.stderr[-2000:]
+
+
+# ------------------------------------------------- fused and tiered paths
+#
+# The bucketed dense/kernel backends serve a store no wider than
+# FUSED_MAX_WIDTH with one fused device program (prefilter, full-width
+# gather, intersect); a wider store takes the host prefilter and the tier
+# plan.  Every store here is narrow, so "tiered" lowers the limit below it.
+
+PATHS = ("fused", "tiered", "host")
+
+
+def _serve_path(co, q, path, monkeypatch, **kw):
+    """Answers and the batch record of ``co``'s engine on one path."""
+    from repro.serve import engine as engine_mod
+
+    with monkeypatch.context() as mp:
+        if path == "tiered":
+            mp.setattr(engine_mod, "FUSED_MAX_WIDTH", 0)
+        got = co.engine.query_batch(q, backend="host" if path == "host" else "dense", **kw)
+    return got, co.engine.last_stats
+
+
+def _trim_unused_rows(co):
+    """Empty the label rows no query reads: L_out of the condensation's sinks
+    and L_in of its sources (a sink reaches, and a source is reached by, no
+    other vertex), so the mixes hold empty rows the length prefilter
+    decides.  Verdicts stay exact."""
+    dag = co.engine._fallback_graph
+    sinks = np.flatnonzero(np.diff(dag.indptr) == 0)
+    sources = np.flatnonzero(np.diff(dag.reverse().indptr) == 0)
+    o = co.engine.oracle.with_updated_rows({int(v): [] for v in sinks},
+                                           {int(v): [] for v in sources})
+    co.engine.refresh(o, epoch=co.engine.epoch)
+
+
+def _mix(g, rng, mix):
+    """The random set (uniform pairs) or the equal set (half reachable),
+    each with every u == v pair and two corner pairs."""
+    from repro.graph.reach import sample_reachability_batch
+
+    if mix == "random":
+        q = rng.integers(0, g.n, size=(1500, 2)).astype(np.int32)
+    else:
+        q = sample_reachability_batch(g, 1500, rng)[0]
+    diag = np.arange(g.n, dtype=np.int32)
+    return np.concatenate([q, np.stack([diag, diag], 1),
+                           np.array([[0, g.n - 1], [g.n - 1, 0]], np.int32)])
+
+
+@pytest.mark.parametrize("mix", ["random", "equal"])
+def test_fused_tiered_and_host_paths_agree_with_bfs(rng, monkeypatch, mix):
+    kinds = {"same": 0, "empty": 0, "level": 0, "labels": 0}
+    for name, g in _graph_families(rng):
+        truth = _truth_matrix(g.n, *g.edges())
+        co = build_oracle(g)
+        _trim_unused_rows(co)
+        q = _mix(g, rng, mix)
+        exp = truth[q[:, 0], q[:, 1]]
+        prefiltered = set()
+        for path in PATHS:
+            got, stats = _serve_path(co, q, path, monkeypatch)
+            assert (got == exp).all(), (name, path, int((got != exp).sum()))
+            assert stats["path"] == (None if path == "host" else path), (name, path)
+            assert not any(stats["degraded"].values()), (name, path, stats["degraded"])
+            prefiltered.add(stats["n_prefiltered"])
+        assert len(prefiltered) == 1, (name, prefiltered)
+        # what decided each pair, in condensation ids
+        o, lv = co.engine.oracle, co.engine.level
+        cu, cv = co.comp[q[:, 0]], co.comp[q[:, 1]]
+        same = cu == cv
+        empty = ~same & ((o.out_len[cu] == 0) | (o.in_len[cv] == 0))
+        level = ~same & ~empty & (lv[cu] >= lv[cv])
+        kinds["same"] += int(same.sum())
+        kinds["empty"] += int(empty.sum())
+        kinds["level"] += int(level.sum())
+        kinds["labels"] += int((~same & ~empty & ~level).sum())
+        assert prefiltered == {int((same | empty | level).sum())}, name
+    assert all(kinds.values()), kinds
+
+
+@pytest.mark.parametrize("rung", ["budget_quarantine", "deadline", "device_fault"])
+def test_paths_agree_on_every_rung(rng, monkeypatch, rung):
+    """Under a half-size budget view with a quarter of the rows
+    quarantined, past the deadline, and with an injected device failure,
+    the three paths give BFS's verdicts and the same prefiltered count."""
+    import time
+    import warnings
+
+    from repro.ft import inject
+    from repro.serve.budget import label_bytes, truncate_store
+
+    for name, g in _graph_families(rng):
+        truth = _truth_matrix(g.n, *g.edges())
+        co = build_oracle(g)
+        q = _mix(g, rng, "equal")
+        exp = truth[q[:, 0], q[:, 1]]
+        kw, fault = {}, None
+        if rung == "budget_quarantine":
+            co.engine.set_budget(truncate_store(co.oracle,
+                                                budget_bytes=label_bytes(co.oracle) // 2))
+            qmask = np.zeros(co.oracle.n, dtype=bool)
+            qmask[rng.integers(0, co.oracle.n, size=max(co.oracle.n // 4, 1))] = True
+            co.engine.set_quarantine(qmask, None)
+        elif rung == "deadline":
+            kw["deadline"] = time.monotonic() - 1.0
+        else:
+            fault = {"serve.device_dispatch": 0}
+        prefiltered = set()
+        for path in PATHS:
+            with warnings.catch_warnings(), inject.active(inject.Injector(fault or {})):
+                warnings.simplefilter("ignore")
+                got, stats = _serve_path(co, q, path, monkeypatch, **kw)
+            deg = stats["degraded"]
+            assert (got == exp).all(), (name, path, int((got != exp).sum()))
+            prefiltered.add(stats["n_prefiltered"])
+            if rung == "budget_quarantine":
+                assert deg["quarantined"] > 0, (name, path)
+                # the tier programs run only for rows the host prefilter left
+                undecided = q.shape[0] - deg["quarantined"] - stats["n_prefiltered"]
+                want = {"fused": "fused", "tiered": "tiered" if undecided else None}
+                assert stats["path"] == want.get(path), (name, path)
+            elif path != "host":
+                kind = "deadline_to_host" if rung == "deadline" else "device_to_host"
+                assert stats["path"] is None and deg[kind] > 0, (name, path, deg)
+        assert len(prefiltered) == 1, (name, rung, prefiltered)
+
+
+def _device_batches(path):
+    from repro.obs import metrics
+
+    return metrics.snapshot()["engine_device_batches_total"]["values"].get(f"path={path}", 0)
+
+
+def test_store_wider_than_a_lane_row_takes_the_tiered_path(rng):
+    import dataclasses
+
+    from repro.graph.csr import INVALID
+    from repro.serve.engine import FUSED_MAX_WIDTH
+
+    g = layered_dag(80, avg_out=2.5, seed=2)
+    truth = _truth_matrix(g.n, *g.edges())
+    o = distribution_labeling(g)
+    q = rng.integers(0, g.n, size=(1000, 2)).astype(np.int32)
+
+    def widen(mat, width):
+        out = np.full((mat.shape[0], width), INVALID, dtype=np.int32)
+        out[:, : mat.shape[1]] = mat
+        return out
+
+    for width, path in ((FUSED_MAX_WIDTH, "fused"), (FUSED_MAX_WIDTH + 8, "tiered")):
+        wide = dataclasses.replace(o, L_out=widen(o.L_out, width), L_in=widen(o.L_in, width))
+        eng = QueryEngine(wide, backend="dense", level=topo_levels(g))
+        before = _device_batches(path)
+        got = eng.query_batch(q)
+        assert (got == truth[q[:, 0], q[:, 1]]).all(), width
+        assert eng.last_stats["path"] == path, width
+        assert _device_batches(path) == before + 1, width
+        assert max(t["width"] for t in eng.last_stats["tiers"]) == width
+
+
+@pytest.mark.parametrize("k", [1, 3, 255, 257])
+def test_pad_rows_never_reach_the_answers(rng, k):
+    """A pad row is (0, 0), which the fused program decides as reachable:
+    a batch of unreachable pairs padded to its tile stays all False."""
+    from repro.serve.planner import tile_rows
+
+    g = random_dag(70, 200, seed=1)
+    truth = _truth_matrix(g.n, *g.edges())
+    eng = QueryEngine(distribution_labeling(g), backend="dense", level=topo_levels(g))
+    neg = np.argwhere(~truth).astype(np.int32)
+    q = neg[rng.choice(neg.shape[0], size=k)]
+    got = eng.query_batch(q)
+    assert got.shape == (k,) and not got.any()
+    assert eng.last_stats["path"] == "fused"
+    assert [(t["count"], t["rows"]) for t in eng.last_stats["tiers"]] == [
+        (k, tile_rows(k, eng.min_tile))]

@@ -3,13 +3,16 @@
   * the ring's timestamps agree with the profiler's host events (one clock),
   * a ``begin``/``end`` span with ``annotate=True`` keeps its annotation open
     across an ``await`` and enters the profile once,
-  * in a daemon run every stage is observed once per tick, and the worker's
-    stages plus the hand-off reconcile with ``daemon_dispatch_ms``,
+  * in a daemon run every stage of the engine's path (the fused program,
+    or the host prefilter and tier plan for a store too wide to fuse) is
+    observed once per tick, and the worker's stages plus the hand-off
+    reconcile with ``daemon_dispatch_ms``,
   * the collector hook records a forced collection and is gone after the
     daemon stops,
   * with obs off no stage is timed.
 """
 import asyncio
+import contextlib
 import gc
 import glob
 import os
@@ -30,6 +33,9 @@ from repro.serve.daemon import DaemonConfig, ServeDaemon
 G = random_dag(400, 1600, seed=11)
 DAEMON_STAGES = ("wait", "collect", "handoff", "pad", "resolve")
 ENGINE_STAGES = ("map", "prefilter", "plan", "enqueue", "sync", "scatter")
+# the stages each device path observes: the fused program has no host
+# prefilter or tier plan
+PATH_STAGES = {"fused": ("map", "enqueue", "sync", "scatter"), "tiered": ENGINE_STAGES}
 
 
 @pytest.fixture(scope="module")
@@ -148,47 +154,71 @@ def _waves(n_waves=6, per_wave=4, pairs=1024, seed=3):
             for _ in range(n_waves)]
 
 
-@pytest.fixture(scope="module")
-def staged_run(co):
-    co.engine.warmup(4096)                     # compiles every shape first
-    metrics.REGISTRY.reset()
-    # a collection landing between two stages would count in no stage; the
-    # collector's pauses have their own test below
-    gc.disable()
-    try:
-        daemon = _serve(co, _waves(40))
-    finally:
-        gc.enable()
-    return daemon, metrics.snapshot()
+@contextlib.contextmanager
+def _on_path(path):
+    """The engine serves G's narrow store on ``path``: "tiered" lowers the
+    fused program's width limit below the store."""
+    with pytest.MonkeyPatch.context() as mp:
+        if path == "tiered":
+            mp.setattr(engine_mod, "FUSED_MAX_WIDTH", 0)
+        yield
+
+
+@pytest.fixture(scope="module", params=sorted(PATH_STAGES))
+def staged_run(co, request):
+    path = request.param
+    with _on_path(path):
+        co.engine.warmup(4096)                 # compiles every shape first
+        metrics.REGISTRY.reset()
+        # a collection landing between two stages would count in no stage;
+        # the collector's pauses have their own test below
+        gc.disable()
+        try:
+            daemon = _serve(co, _waves(40))
+        finally:
+            gc.enable()
+        return path, daemon, metrics.snapshot()
+
+
+def _stage_values(snap, family):
+    return {label.split("=", 1)[1]: v for label, v in snap[family]["values"].items()}
 
 
 def test_every_stage_observed_once_per_tick(staged_run):
-    daemon, _ = staged_run
+    path, daemon, snap = staged_run
     ticks = daemon.counters["batches"]
     assert ticks > 0 and daemon.counters["device_batches"] == ticks
-    d = _histograms("daemon_stage_ms")
-    e = _histograms("engine_stage_ms")
+    d = _stage_values(snap, "daemon_stage_ms")
+    e = _stage_values(snap, "engine_stage_ms")
+    stages = PATH_STAGES[path]
     assert {s: d[s]["count"] for s in DAEMON_STAGES} == dict.fromkeys(DAEMON_STAGES, ticks)
-    assert {s: e[s]["count"] for s in ENGINE_STAGES} == dict.fromkeys(ENGINE_STAGES, ticks)
+    assert {s: e[s]["count"] for s in stages} == dict.fromkeys(stages, ticks)
+    assert all(e[s]["count"] == 0 for s in e if s not in stages)
     assert all(v["sum"] >= 0 for v in list(d.values()) + list(e.values()))
+    assert _stage_values(snap, "engine_device_batches_total") == {
+        p: ticks if p == path else 0 for p in PATH_STAGES}
 
 
 def test_worker_stages_reconcile_with_dispatch_ms(staged_run):
-    _, snap = staged_run
-    d = _histograms("daemon_stage_ms")
-    e = _histograms("engine_stage_ms")
+    path, _, snap = staged_run
+    d = _stage_values(snap, "daemon_stage_ms")
+    e = _stage_values(snap, "engine_stage_ms")
     dispatch = snap["daemon_dispatch_ms"]["values"][""]["sum"]
-    parts = d["handoff"]["sum"] + d["pad"]["sum"] + sum(e[s]["sum"] for s in ENGINE_STAGES)
+    parts = (d["handoff"]["sum"] + d["pad"]["sum"]
+             + sum(e[s]["sum"] for s in PATH_STAGES[path]))
     assert parts <= dispatch * 1.0001
     assert parts >= 0.9 * dispatch, (parts, dispatch)
 
 
-def test_stage_spans_are_in_the_ring(co):
+@pytest.mark.parametrize("path", sorted(PATH_STAGES))
+def test_stage_spans_are_in_the_ring(co, path):
     trace.TRACER.clear()
-    _serve(co, _waves(2))
+    with _on_path(path):
+        _serve(co, _waves(2))
     names = {e["name"] for e in trace.TRACER.events}
     assert {f"daemon.{s}" for s in ("wait", "collect", "pad", "resolve")} <= names
-    assert {f"engine.{s}" for s in ENGINE_STAGES} <= names
+    assert {f"engine.{s}" for s in PATH_STAGES[path]} <= names
+    assert not {f"engine.{s}" for s in ENGINE_STAGES if s not in PATH_STAGES[path]} & names
     assert {"dispatch_tick", "dispatch"} <= names
     assert "device_call" not in names
 
